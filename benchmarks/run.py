@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.distributed import is_main, main_print
 
 OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "out")
@@ -1046,6 +1047,7 @@ BENCHES = {
 
 
 def main(argv=None):
+    enable_compile_cache()
     from benchmarks.figures import FULL, SMOKE, BenchProfile
 
     ap = argparse.ArgumentParser()

@@ -1,6 +1,10 @@
 #!/usr/bin/env bash
 # 2-process jax.distributed CPU smoke (the CI multihost leg).
 #
+# CPU only: it forces virtual CPU devices and starts several JAX
+# processes. Never run it on a TPU host, where a chip belongs to one
+# process (use `python chip_smoke.py --four-chips` there).
+#
 # Launches NUM_PROCESSES copies of repro.launch.distributed on localhost,
 # each with LOCAL_DEVICES virtual CPU devices, sharing one coordinator.
 # Each process asserts the global topology (process count/index, local vs

@@ -7,12 +7,6 @@ chunk executable vs the grid's one-program trace. XLA freely reassociates
 constant factors and refuses op chains per context, drifting results by a
 ulp per round; ``jax.lax.optimization_barrier`` pins a value so no op can
 be fused, hoisted, or folded across it.
-
-jax (as of 0.4.x) ships no vmap batching rule for the barrier primitive,
-which would break ``vmap``-based drivers (``run_sweep``) over fenced steps.
-The barrier is shape-preserving and value-transparent per operand, so the
-batching rule is the identity on batch dims — registered here, guarded so a
-future jax that grows its own rule (or moves the primitive) wins.
 """
 
 from __future__ import annotations
@@ -24,20 +18,3 @@ def pin(x):
     """Pin a value (or pytree) into its own XLA fusion island."""
     return jax.lax.optimization_barrier(x)
 
-
-def _register_barrier_batching_rule():
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:  # future jax moved internals; rely on upstream rule
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return  # upstream (or a previous import) already provides one
-
-    def _batch_rule(args, dims):
-        return jax.lax.optimization_barrier(tuple(args)), dims
-
-    batching.primitive_batchers[optimization_barrier_p] = _batch_rule
-
-
-_register_barrier_batching_rule()

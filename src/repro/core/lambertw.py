@@ -48,14 +48,15 @@ def lambertw0(z: jax.Array) -> jax.Array:
     z = jnp.maximum(z.astype(dt), 0.0)
     w = _initial_guess(z).astype(dt)
 
-    def halley(w, _):
+    def halley(_, w):
         ew = jnp.exp(w)
         f = w * ew - z
         # Halley: w' = w - f / (ew*(w+1) - (w+2) f / (2w+2))
         denom = ew * (w + 1.0) - (w + 2.0) * f / (2.0 * w + 2.0)
         # denom > 0 for w >= 0; protect anyway.
         step = f / jnp.where(jnp.abs(denom) < 1e-30, 1e-30, denom)
-        return w - step, None
+        return w - step
 
-    w, _ = jax.lax.scan(halley, w, None, length=_HALLEY_ITERS)
-    return w
+    # a fori_loop (not a scan): the Pallas TPU lowering accepts only a
+    # counted loop inside the fused decision kernel
+    return jax.lax.fori_loop(0, _HALLEY_ITERS, halley, w)

@@ -175,7 +175,7 @@ def solve_candidates_coeffs(gains: jax.Array, z: jax.Array, c: SolveCoeffs):
     q_int = _q_eq17_c(p_int, gains, z, c)
 
     # Boundary candidate: P = Pmax (also Algorithm 2's t=0 branch when Z=0).
-    p_bnd = jnp.full_like(gains, c.p_max)
+    p_bnd = jnp.broadcast_to(c.p_max, gains.shape)
     q_bnd = _q_eq17_c(p_bnd, gains, z, c)
 
     # Keep the smaller objective (replaces the Hessian determinant test).

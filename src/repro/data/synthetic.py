@@ -18,11 +18,16 @@ which is what the time-to-accuracy comparisons (Figs. 2-4) need.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["client_images", "client_labels",
+                                "test_images", "test_labels"],
+                   meta_fields=["n_classes"])
 @dataclasses.dataclass
 class FederatedDataset:
     """Client-partitioned dataset with a common test split.
@@ -33,6 +38,10 @@ class FederatedDataset:
     token sequences, ``client_labels`` the matching (N, per_client, S)
     next-token targets — the engines only ever index the leading two axes,
     so both layouts flow through the same round machinery.
+
+    A pytree (``n_classes`` static), so the engines pass it through their
+    jit boundary as an argument: a closed-over dataset would be baked into
+    the compiled program as a constant.
     """
 
     client_images: jax.Array     # (N, per_client, H, W, C) | (N, per_client, S)

@@ -496,36 +496,12 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
         sharded = shard_map(shard_body_pop, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs)
 
-    # On a composed mesh with a real 'part' extent, every value entering
-    # the shard_map must be pinned FULLY REPLICATED first: jax 0.4.37's
-    # GSPMD assembles an in-jit-produced operand that is client-sharded but
-    # part-replicated with a dynamic-update-slice + all-reduce over ALL
-    # mesh devices, double-counting the part columns (observed: operands
-    # arrive multiplied by the 'part' extent). Replicated operands reshard
-    # into the manual region with a local slice — no collective, no bug —
-    # at the cost of materializing the (N,) operands per device (which is
-    # GSPMD's default placement without hints anyway).
-    repl2d = dict(mesh.shape).get("part", 1) > 1
-
-    def replicate2d(x):
-        if not repl2d:
-            return x
-        return jax.tree.map(
-            lambda a: a if jnp.ndim(a) == 0
-            else jax.lax.with_sharding_constraint(
-                a, NamedSharding(mesh, P())), x)
-
     def constrain(raw):
         # the raws are drawn full-shape OUTSIDE the shard_map (mesh-
         # invariant bits); without a placement hint GSPMD materializes the
         # whole (N,) draw on every device. The constraint shards the draw
         # output across the client mesh — purely a placement choice, the
-        # values are untouched (verified bit-exact), worth ~15% at N=10^6.
-        # (On a part>1 mesh the client-sharded placement is the buggy
-        # reshard above — replicate2d then pins the padded operands
-        # instead, and this hint is skipped.)
-        if repl2d:
-            return raw
+        # values are untouched (verified bit-exact)
         return jax.tree.map(
             lambda x: x if jnp.ndim(x) == 0
             else jax.lax.with_sharding_constraint(
@@ -539,18 +515,11 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
         z = pad_client_axis(pol_state.z, n_pad, 0.0)
         aux = pad_client_axis(pol_state.aux, n_pad, 0.0)
         cst = pad_client_axis(ch_state, n_pad, 0.0)
-        raw_ch, raw_pol, z, aux, cst = replicate2d(
-            (raw_ch, raw_pol, z, aux, cst))
         (t_comm, power, n_sel, sel_idx, sel_valid, q_sel, z, aux, t,
          cst) = sharded(raw_ch, raw_pol, z, aux, pol_state.t, cst, sig_pad,
                         co)
-        # exit-side pin (same bug, other direction): the sliced state is
-        # client-sharded + part-replicated; left unconstrained, a scan
-        # carrying it picks a layout whose in-loop reshard goes through
-        # the buggy subgroup assembly. Replicated carries are safe.
-        z, aux, cst = replicate2d((z[:n], aux[:n], cst[..., :n]))
         return (t_comm, power, n_sel, sel_idx, sel_valid, q_sel,
-                PolicyState(z, aux, t), cst)
+                PolicyState(z[:n], aux[:n], t), cst[..., :n])
 
     def schedule_pop(raw_ch, raw_pol, raw_pop, pol_state: PolicyState,
                      ch_state, co):
@@ -568,17 +537,11 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
         z = pad_client_axis(pol_state.z, n_pad, 0.0)
         aux = pad_client_axis(pol_state.aux, n_pad, 0.0)
         cst = pad_client_axis(cst, n_pad, 0.0)
-        (raw_ch, raw_pol, raw_churn, raw_fail, active, z, aux,
-         cst) = replicate2d((raw_ch, raw_pol, raw_churn, raw_fail, active,
-                             z, aux, cst))
         (t_comm, power, n_sel, sel_idx, sel_valid, q_sel, z, aux, t, cst,
          active) = sharded(raw_ch, raw_pol, raw_churn, raw_fail, active, z,
                            aux, pol_state.t, cst, sig_pad, co)
-        # exit-side pin — see schedule() above
-        z, aux, cst, active = replicate2d(
-            (z[:n], aux[:n], cst[..., :n], active[:n]))
         return (t_comm, power, n_sel, sel_idx, sel_valid, q_sel,
-                PolicyState(z, aux, t), (cst, active))
+                PolicyState(z[:n], aux[:n], t), (cst[..., :n], active[:n]))
 
     return schedule if pcfg is None else schedule_pop
 

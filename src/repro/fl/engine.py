@@ -400,10 +400,11 @@ def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
     drive many simulations (benchmarks, sweeps over checkpoints) can build
     once, warm each chunk length, and reuse the compiled function.
 
-    The decision-layer coefficient bundle crosses the jit boundary as a
-    runtime argument (supplied by the returned wrapper) — the operand
-    contract that makes the engine's per-round decisions bitwise-equal to
-    the multi-tenant service's (``repro/fl/decision.py``).
+    The decision-layer coefficient bundle and the dataset cross the jit
+    boundary as runtime arguments (supplied by the returned wrapper): the
+    dataset so that it is not baked into the program, the bundle by the
+    operand contract that makes the engine's per-round decisions
+    bitwise-equal to the multi-tenant service's (``repro/fl/decision.py``).
 
     Telemetry (``repro.obs``, follows the process-wide ``configure``
     switch): each chunk length's first call counts an
@@ -414,22 +415,22 @@ def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
     async overlap for live queue visibility, and changes no numerics
     (the returned carry is bitwise the same; tests/test_obs.py).
     """
-    eval_fn = make_eval_fn(ds, sim)
     co_host = decision_coeffs(scfg, ch)
     ei = EngineInstruments(obs_metrics.default_registry())
 
     @functools.partial(jax.jit, static_argnames=("n_rounds",),
                        donate_argnums=(0,))
-    def _run_chunk(carry, co, n_rounds):
-        sim_round = make_sim_round(ds, sim, scfg, ch, sigmas, solve_fn,
+    def _run_chunk(carry, co, data, n_rounds):
+        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, solve_fn,
                                    coeffs=co)
-        return scan_chunk(sim_round, eval_fn, carry, n_rounds)
+        return scan_chunk(sim_round, make_eval_fn(data, sim), carry,
+                          n_rounds)
 
     def run_chunk(carry, n_rounds):
         fresh = ei.compiles.miss(("run_chunk", n_rounds),
                                  entry="run_chunk", n_rounds=n_rounds)
         t0 = perf()
-        carry, acc, nsel = _run_chunk(carry, co_host, n_rounds)
+        carry, acc, nsel = _run_chunk(carry, co_host, ds, n_rounds)
         if fresh:
             # jit traces + compiles synchronously at call time
             ei.compiles.compile_s.inc(perf() - t0)
@@ -523,24 +524,25 @@ def make_config_runner(ds: FederatedDataset, sim: SimConfig,
     (comm_cum, test_acc, power_cum, n_selected)``, each (E,).
 
     The coefficient bundle rides the jit boundary as a runtime argument
-    (operand contract, ``repro/fl/decision.py``)."""
-    eval_fn = make_eval_fn(ds, sim)
+    (operand contract, ``repro/fl/decision.py``), and so does the dataset,
+    which would otherwise be baked into the program as a constant."""
     channel = make_channel(sim.channel, sigmas, ch,
                            **dict(sim.channel_params))
     n = scfg.n_clients
     co_host = decision_coeffs(scfg, ch)
 
     @jax.jit
-    def _runner(params, key, co):
-        sim_round = make_sim_round(ds, sim, scfg, ch, sigmas, solve_fn,
+    def _runner(params, key, co, data):
+        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, solve_fn,
                                    coeffs=co)
         pol0 = init_policy_state(sim.policy, n)
         ch0 = init_channel_carry(key, sim, channel, n)
-        return run_config_chunks(sim_round, eval_fn, sim.rounds,
-                                 sim.eval_every, params, pol0, ch0, key)
+        return run_config_chunks(sim_round, make_eval_fn(data, sim),
+                                 sim.rounds, sim.eval_every, params, pol0,
+                                 ch0, key)
 
     def runner(params, key):
-        return _runner(params, key, co_host)
+        return _runner(params, key, co_host, ds)
 
     return runner
 

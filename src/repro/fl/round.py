@@ -258,23 +258,6 @@ def make_sharded_round_update(loss_fn: Callable, gamma: float, steps: int,
         in_specs=(P(), P("part"), P("part"), P("part"), P("part")),
         out_specs=P())
 
-    # On a shared mesh with other real axes (the composed 2D round), pin
-    # every operand fully replicated before the shard_map: jax 0.4.37's
-    # GSPMD assembles an in-jit-produced part-sharded / client-replicated
-    # operand with an all-reduce over ALL mesh devices, double-counting
-    # the replicated columns (see fl/client_shard.py's replicate2d — this
-    # is the same bug with the axes' roles swapped). Replicated operands
-    # enter the manual region as a local slice, collective-free.
-    repl2d = any(extent > 1 for name, extent in dict(mesh.shape).items()
-                 if name != "part")
-
-    def _replicate(x):
-        if not repl2d or jnp.ndim(x) == 0:
-            return x
-        from jax.sharding import NamedSharding
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P()))
-
     def update(params, inputs, labels, sel_valid, q_sel):
         m = sel_valid.shape[0]
         pad = (-m) % n_shards
@@ -288,8 +271,6 @@ def make_sharded_round_update(loss_fn: Callable, gamma: float, steps: int,
             sel_valid = jnp.concatenate(
                 [sel_valid, jnp.zeros((pad,), sel_valid.dtype)])
             q_sel = jnp.concatenate([q_sel, jnp.ones((pad,), q_sel.dtype)])
-        params, inputs, labels, sel_valid, q_sel = jax.tree.map(
-            _replicate, (params, inputs, labels, sel_valid, q_sel))
         return sharded(params, inputs, labels, sel_valid, q_sel)
 
     return update

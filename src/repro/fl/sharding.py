@@ -2,10 +2,8 @@
 
 Two layers live here:
 
-* the ``shard_map`` compatibility shim (jax >= 0.5 promotes ``shard_map``
-  out of experimental and renames the replication-check flag
-  ``check_rep`` -> ``check_vma``; every caller needs the check OFF because
-  the bodies close over unpartitioned constants).
+* ``shard_map`` with the replication check off (every caller needs it off
+  because the bodies close over unpartitioned constants).
 * the **mesh-invariant blocked reduction** behind the client-sharded
   scheduling path's accounting contract: a float32 sum over the (N,)
   client axis whose ASSOCIATION does not depend on how many devices the
@@ -34,19 +32,12 @@ Two layers live here:
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
 from repro.core.fences import pin
-
-try:  # jax >= 0.5 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 # Fixed association width of the accounting reduce. Constant across mesh
 # sizes BY DESIGN (cross-mesh bit-equality needs every mesh to add the same
@@ -59,12 +50,9 @@ ACCOUNT_BLOCKS = 96
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check off, across jax versions."""
-    flags = inspect.signature(_shard_map).parameters
-    kw = ({"check_rep": False} if "check_rep" in flags
-          else {"check_vma": False} if "check_vma" in flags else {})
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map`` with the replication check off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh2d(client_shards: int, part_shards: int,

@@ -13,8 +13,9 @@ performs the whole post-observation decision in a single tiled pass:
   fixed-iteration Halley Lambert-W), evaluated per block. Reusing the
   oracle's exact op sequence (rather than restating it, as the
   solve-only kernel must for its baked-constant signature) is what makes
-  the fused path BITWISE-equal to the stitched composition, not merely
-  round-off-close.
+  the interpreted fused path BITWISE-equal to the stitched composition.
+  Compiled for the chip, Mosaic and XLA lower exp/log differently, so
+  the two agree to round-off (``chip_smoke.py`` states the tolerance).
 * population activity mask (PR-6 semantics) — inactive lanes are forced
   to q = 0 BEFORE selection, so they can never be drawn and contribute
   exactly 0 expected power; their queues still drain by
@@ -59,11 +60,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scheduler import (SolveCoeffs, solve_round_coeffs,
                                   update_queues_z)
 
 _BLOCK = 1024  # 8 sublanes x 128 lanes
+_ROWS = 8      # batched kernel: bucket rows per block (the sublane tile)
 
 # Operand-vector layout: SolveCoeffs' 11 fields in declaration order,
 # then the 3 AccountCoeffs fields. Indexing is positional on purpose —
@@ -84,15 +87,19 @@ def pack_decision_operands(solve, acct) -> jax.Array:
     return jnp.stack([jnp.asarray(x, jnp.float32) for x in leaves])
 
 
-def _decision_lanes(ops, gains, z, u, active, valid):
+def _decision_lanes(ops, gains, z, u, active, valid, fence: bool):
     """The per-lane decision math, shared by the 1D and batched kernels.
 
-    ``ops`` is the flat (14,) operand vector for this row; ``active`` /
-    ``valid`` are optional boolean lanes (None = all-on, resolved at trace
-    time so the mask-free kernels carry no dead loads).
+    ``ops(i)`` reads operand ``i`` of the (14,) vector for these lanes (an
+    SMEM scalar in the 1D kernel, an (8, 1) column of bucket rows in the
+    batched one); ``active`` / ``valid`` are optional boolean lanes (None =
+    all-on, resolved at trace time so the mask-free kernels carry no dead
+    loads). ``fence`` keeps the interpreted body's optimization barrier
+    (see below); Mosaic has no lowering for it and fuses nothing across a
+    kernel body, so the compiled kernel leaves it out.
     """
-    c = SolveCoeffs(*(ops[i] for i in range(_N_SOLVE)))
-    ell, bw, n0 = (ops[_N_SOLVE], ops[_N_SOLVE + 1], ops[_N_SOLVE + 2])
+    c = SolveCoeffs(*(ops(i) for i in range(_N_SOLVE)))
+    ell, bw, n0 = (ops(_N_SOLVE), ops(_N_SOLVE + 1), ops(_N_SOLVE + 2))
     q, p = solve_round_coeffs(gains, z, c)
     if active is not None:
         # population semantics: inactive lanes cannot be selected and
@@ -100,11 +107,12 @@ def _decision_lanes(ops, gains, z, u, active, valid):
         q = jnp.where(active, q, 0.0)
     sel = u < q
     z_new = update_queues_z(z, q, p, c)
-    # fence the decision outputs before the accounting summands, exactly
-    # where decision_step fences: without it the compiler recomputes p
-    # inside the tc fusion with different contraction (1-ulp drift vs the
-    # stitched path, which derives rate from the materialized p)
-    sel, q, p, z_new = jax.lax.optimization_barrier((sel, q, p, z_new))
+    if fence:
+        # fence the decision outputs before the accounting summands,
+        # exactly where decision_step fences: without it XLA recomputes p
+        # inside the tc fusion with different contraction (1-ulp drift vs
+        # the stitched path, which derives rate from the materialized p)
+        sel, q, p, z_new = jax.lax.optimization_barrier((sel, q, p, z_new))
     # same expression as repro.core.scheduler.coeff_rate, on operand scalars
     rate = bw * jnp.log2(1.0 + gains * p / n0)
     tc = ell / jnp.maximum(rate, 1e-9)  # unmasked: caller gates on final sel
@@ -114,18 +122,21 @@ def _decision_lanes(ops, gains, z, u, active, valid):
     return sel, q, p, z_new, tc, pq
 
 
-def _make_kernel(has_active: bool, has_valid: bool, batched: bool):
+def _make_kernel(has_active: bool, has_valid: bool, batched: bool,
+                 fence: bool):
     def kernel(ops_ref, g_ref, z_ref, u_ref, *refs):
         n_masks = int(has_active) + int(has_valid)
         masks = [r[...] for r in refs[:n_masks]]
         sel_ref, q_ref, p_ref, zn_ref, tc_ref, pq_ref = refs[n_masks:]
-        ops = ops_ref[...]
         if batched:
-            ops = ops[0]
+            ops = ops_ref[...]
+            col = lambda i: ops[:, i:i + 1]  # noqa: E731  (rows, 1)
+        else:
+            col = lambda i: ops_ref[i]  # noqa: E731  SMEM scalar
         active = masks[0] if has_active else None
         valid = (masks[1] if has_active else masks[0]) if has_valid else None
         sel, q, p, z_new, tc, pq = _decision_lanes(
-            ops, g_ref[...], z_ref[...], u_ref[...], active, valid)
+            col, g_ref[...], z_ref[...], u_ref[...], active, valid, fence)
         sel_ref[...] = sel
         q_ref[...] = q
         p_ref[...] = p
@@ -136,6 +147,8 @@ def _make_kernel(has_active: bool, has_valid: bool, batched: bool):
 
 
 def _resolve_interpret(interpret):
+    """``None`` -> interpret off-TPU. Only the CPU tests rely on this; a
+    caller that must run on the chip passes ``interpret=False``."""
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
@@ -192,9 +205,10 @@ def decision_fused(gains: jax.Array, z: jax.Array, u: jax.Array,
             lanes.append(_pad_lane(m, pad, False))
     n_pad = lanes[0].shape[0]
     bs = pl.BlockSpec((block,), lambda i: (i,))
-    obs = pl.BlockSpec((N_DECISION_OPS,), lambda i: (0,))
+    obs = pl.BlockSpec(memory_space=pltpu.SMEM)
     outs = pl.pallas_call(
-        _make_kernel(active is not None, valid is not None, batched=False),
+        _make_kernel(active is not None, valid is not None, batched=False,
+                     fence=interpret),
         grid=(n_pad // block,),
         in_specs=[obs] + [bs] * len(lanes),
         out_specs=[bs] * 6,
@@ -212,16 +226,20 @@ def decision_fused_batched(gains: jax.Array, z: jax.Array, u: jax.Array,
     """Bucket-batched fused decision for the service: (B, N) rows, one
     (14,) operand row per bucket slot.
 
-    Pallas calls do not batch under ``vmap`` on the pinned jax, so the
-    service's fused path uses this natively 2D grid — ``(B, N/block)``
-    with one bucket row per grid row and the row's operand vector
-    broadcast along the lane axis — and vmaps only the (cheap) stitched
-    guarantee/accounting epilogue. ``ops`` is (B, 14); heterogeneous
-    tenants batch together because coefficients are runtime operands.
+    Pallas calls do not batch under ``vmap``, so the service's fused path
+    uses this natively 2D grid and vmaps only the (cheap) stitched
+    guarantee/accounting epilogue. Each block holds 8 bucket rows (B pads
+    to a multiple of 8) and ``block`` lanes — or the whole row when N <=
+    ``block``, which the (8, 128) tiling rule admits as a full dimension.
+    The rows' operands arrive as an (8, 14) block of ``ops`` (B, 14) and
+    broadcast along the lanes as (8, 1) columns; heterogeneous tenants
+    batch together because coefficients are runtime operands.
 
     Same returns/hygiene as :func:`decision_fused`, batched: each output
-    is (B, N). The service does NOT activity-mask q (pads are neutralised
-    by gains = 0 -> q = q_floor and raw = 2.0), so only ``valid`` exists.
+    is (B, N). Pad rows repeat the last operand row and carry the lane pad
+    fills, so they stay finite, and are sliced off. The service does NOT
+    activity-mask q (pads are neutralised by gains = 0 -> q = q_floor and
+    raw = 2.0), so only ``valid`` exists.
     """
     if block <= 0:
         raise ValueError(f"block must be positive, got {block}")
@@ -231,23 +249,31 @@ def decision_fused_batched(gains: jax.Array, z: jax.Array, u: jax.Array,
     assert ops.shape == (b, N_DECISION_OPS)
     if n_real == 0 or b == 0:
         raise ValueError("decision_fused_batched needs a non-empty bucket")
-    pad = (-n_real) % block
-    lanes = [_pad_lane(gains.astype(jnp.float32), pad, 1.0),
-             _pad_lane(z.astype(jnp.float32), pad),
-             _pad_lane(u, pad, 2.0)]
+    lane_block = min(block, n_real)
+    pad = (-n_real) % lane_block
+    pad_b = (-b) % _ROWS
+
+    def pad2(x, fill):
+        return jnp.pad(_pad_lane(x, pad, fill), [(0, pad_b), (0, 0)],
+                       constant_values=jnp.asarray(fill, x.dtype))
+
+    lanes = [pad2(gains.astype(jnp.float32), 1.0),
+             pad2(z.astype(jnp.float32), 0.0), pad2(u, 2.0)]
     if valid is not None:
         assert valid.shape == gains.shape
-        lanes.append(_pad_lane(valid, pad, False))
-    n_pad = lanes[0].shape[1]
-    bs = pl.BlockSpec((1, block), lambda r, i: (r, i))
-    obs = pl.BlockSpec((1, N_DECISION_OPS), lambda r, i: (r, 0))
+        lanes.append(pad2(valid, False))
+    ops = jnp.pad(ops.astype(jnp.float32), [(0, pad_b), (0, 0)], mode="edge")
+    b_pad, n_pad = lanes[0].shape
+    bs = pl.BlockSpec((_ROWS, lane_block), lambda r, i: (r, i))
+    obs = pl.BlockSpec((_ROWS, N_DECISION_OPS), lambda r, i: (r, 0))
     outs = pl.pallas_call(
-        _make_kernel(False, valid is not None, batched=True),
-        grid=(b, n_pad // block),
+        _make_kernel(False, valid is not None, batched=True,
+                     fence=interpret),
+        grid=(b_pad // _ROWS, n_pad // lane_block),
         in_specs=[obs] + [bs] * len(lanes),
         out_specs=[bs] * 6,
-        out_shape=[jax.ShapeDtypeStruct((b, n_pad), jnp.bool_)]
-        + [jax.ShapeDtypeStruct((b, n_pad), jnp.float32)] * 5,
+        out_shape=[jax.ShapeDtypeStruct((b_pad, n_pad), jnp.bool_)]
+        + [jax.ShapeDtypeStruct((b_pad, n_pad), jnp.float32)] * 5,
         interpret=interpret,
     )(ops, *lanes)
-    return tuple(o[:, :n_real] for o in outs)
+    return tuple(o[:b, :n_real] for o in outs)

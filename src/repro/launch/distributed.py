@@ -25,13 +25,15 @@ What multi-process does NOT change: the numeric contract. The composed
 ``(Dc, Dp)`` mesh as a 1-process x 8-device run and the per-device
 programs are identical; only the device->process placement differs.
 
-CPU caveat (pinned by tests/test_multihost.py and the CI smoke): jax
-0.4.x's CPU backend implements the distributed *runtime* (coordinator,
-topology exchange, global device enumeration) but NOT cross-process
-collectives ("Multiprocess computations aren't implemented on the CPU
-backend"). The smoke therefore asserts topology + runs process-LOCAL
-compute only; cross-process shard_map execution needs a real TPU/GPU
-backend and is exercised there by the same entry point, unchanged.
+CPU only: the multi-process smoke (:func:`main`, driven by
+``scripts/run_multihost.sh`` and tests/test_multihost.py) forces virtual
+CPU device counts (``local_device_count``) and starts several processes.
+Never run it on a chip: a chip belongs to one process, and one process
+drives all the chips of a host (``chip_smoke.py --four-chips``). jax's CPU
+backend implements the distributed *runtime* (coordinator, topology
+exchange, global device enumeration) but NOT cross-process collectives
+("Multiprocess computations aren't implemented on the CPU backend"), so
+the smoke asserts topology and runs process-LOCAL compute only.
 """
 
 from __future__ import annotations
@@ -121,8 +123,9 @@ def initialize(coordinator_address: str | None = None,
 
 
 def main(argv=None) -> int:
-    """Multi-process smoke: init, assert topology, process-local compute.
+    """Multi-process CPU smoke: init, assert topology, process-local compute.
 
+    CPU only — it forces virtual CPU devices; never run it on a chip.
     Run one copy per process (scripts/run_multihost.sh drives 2 on
     localhost CPU). Asserts the distributed runtime agrees with the
     launcher's topology flags, runs a jitted reduction on LOCAL devices

@@ -280,7 +280,7 @@ def run_case(arch: str, shape: str, multi_pod: bool, *, debug_mesh=False,
         lowered = jax.jit(step, out_shardings=out_specs).lower(*args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
 
     coll = collective_bytes(hlo)
@@ -388,15 +388,6 @@ def _probe_cfg(cfg: ModelConfig, k_periods: int, k_enc: int) -> ModelConfig:
     )
 
 
-def _cost_dict(compiled):
-    """compiled.cost_analysis() across jax versions: < 0.4.27 returns a
-    one-dict-per-computation list; newer versions return the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return cost
-
-
 def _case_costs(cfg, case, mesh, mode, fl_clients, local_steps,
                 aggregation="paper", remat=False):
     if case.kind == "train":
@@ -410,7 +401,7 @@ def _case_costs(cfg, case, mesh, mode, fl_clients, local_steps,
         step, args, out_specs = build_decode(cfg, case, mesh, mode)
     with mesh:
         compiled = jax.jit(step, out_shardings=out_specs).lower(*args).compile()
-        cost = _cost_dict(compiled) or {}
+        cost = compiled.cost_analysis() or {}
         coll = collective_bytes(compiled.as_text())
     return {"flops": cost.get("flops", 0.0),
             "bytes": cost.get("bytes accessed", 0.0),

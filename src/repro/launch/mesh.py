@@ -16,16 +16,17 @@ participant; cross-pod traffic is the scheduled uplink analogue.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(*, multi_pod: bool = False):
     """Small mesh for in-test dry-runs (8 forced host devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -9,13 +9,13 @@ serve groups as labelled spans.
 Annotations cost a call into the profiler even when no trace is being
 captured, so :func:`trace_span` is a no-op unless process-wide telemetry
 is on (``repro.obs.configure(True)``) — the hot path pays one bool check.
-It also degrades to a no-op on jax versions without ``TraceAnnotation``,
-keeping the oldest-supported-jax CI leg green.
 """
 
 from __future__ import annotations
 
 import contextlib
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import metrics
 
@@ -29,9 +29,5 @@ def trace_span(name: str):
     ...     dispatch_group(...)
     """
     if not metrics.enabled():
-        return _NULL
-    try:
-        from jax.profiler import TraceAnnotation
-    except ImportError:      # pragma: no cover - old jax fallback
         return _NULL
     return TraceAnnotation(name)

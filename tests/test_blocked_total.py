@@ -139,6 +139,9 @@ def test_real_shard_map_matches_emulation():
 
 # -------------------------------------------------------- hypothesis leg
 
+# hypothesis takes only float32-representable bounds at width=32
+F32_1E30 = float(np.float32(1e30))
+
 @settings(max_examples=40, deadline=None)
 @given(st.data())
 def test_property_arbitrary_vectors(data):
@@ -146,7 +149,7 @@ def test_property_arbitrary_vectors(data):
     bitwise across every divisor split (including ragged final blocks)."""
     n = data.draw(st.integers(min_value=1, max_value=500), label="n")
     vals = data.draw(
-        st.lists(st.floats(min_value=-1e30, max_value=1e30, width=32,
+        st.lists(st.floats(min_value=-F32_1E30, max_value=F32_1E30, width=32,
                            allow_nan=False, allow_infinity=False),
                  min_size=n, max_size=n),
         label="vals")

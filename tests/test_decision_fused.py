@@ -11,7 +11,7 @@ to the stitched path, which must pass through unperturbed — the 6-policy
 x 4-channel sweep pins exactly that.
 
 Runs in interpret mode on CPU CI; the ``pallas`` marker re-runs the file
-on the nightly jax-pin/jax-latest kernel-parity legs.
+on the nightly kernel-parity leg.
 """
 
 import dataclasses
@@ -270,18 +270,27 @@ def test_masked_population_decision_across_channels():
 # ---------------------------------------------------------------------------
 
 def test_sharded_mesh1_bitwise():
-    """client_shards=1 fused == sequential jnp, bitwise (the mesh-1
-    contract the stitched sharded path already carries)."""
+    """client_shards=1 fused == client_shards=1 stitched, bitwise; and
+    against the sequential jnp runner, n_sel exact with float accounting
+    to ~1 ulp — the contract every other mesh carries. (XLA:CPU emits the
+    same accounting ops one ulp apart inside the scanned shard_map
+    program: the stitched sharded runner drifts the same way.)"""
     from repro.fl.client_shard import make_schedule_runner
     n = 401
     scfg = dataclasses.replace(CFG, n_clients=n)
     sigmas = jnp.ones((n,), jnp.float32)
     key = jax.random.PRNGKey(21)
-    ref = make_schedule_runner(sigmas, scfg, CH, rounds=4, solver="jnp")(key)
+    seq = make_schedule_runner(sigmas, scfg, CH, rounds=4, solver="jnp")(key)
+    ref = make_schedule_runner(sigmas, scfg, CH, rounds=4, solver="jnp",
+                               client_shards=1)(key)
     out = make_schedule_runner(sigmas, scfg, CH, rounds=4,
                                solver="pallas_fused", client_shards=1)(key)
     for x, y in zip(ref, out):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    t_comm, power, n_sel = (np.asarray(x) for x in out)
+    np.testing.assert_array_equal(n_sel, np.asarray(seq[2]))
+    np.testing.assert_allclose(t_comm, np.asarray(seq[0]), rtol=3e-7, atol=0)
+    np.testing.assert_allclose(power, np.asarray(seq[1]), rtol=3e-7, atol=0)
 
 
 def test_sharded_rejects_fused_baselines():
