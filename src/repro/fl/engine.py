@@ -54,6 +54,13 @@ bitwise-equal to an engine decision.
 Round math is deliberately NOT shared with the legacy loop engine — the
 parity test (tests/test_engine.py) checks two independent implementations
 against each other on the same PRNG key.
+
+Names in a profiler trace: the chunk runner's host span ``fl.dispatch``
+(``repro.obs.span``) covers each call of the jitted chunk, and named
+scopes put the round's device operations under ``fl.decision`` (channel
+observation and decision), ``fl.update`` (packing, batch sampling, local
+SGD, aggregation) and ``fl.eval`` (the chunk's evaluation) in their HLO
+``op_name`` metadata. Scopes change metadata only, never a result.
 """
 
 from __future__ import annotations
@@ -79,6 +86,7 @@ from repro.fl.round import (local_sgd, make_sharded_round_update,
 from repro.models.registry import make_model
 from repro.obs import metrics as obs_metrics
 from repro.obs.instrument import EngineInstruments, perf
+from repro.obs.profile import span
 
 # fold_in tag consumed by stateful channel inits (keeps the round-key chain
 # identical to the stateless models', so rayleigh trajectories are unchanged)
@@ -242,26 +250,29 @@ def make_round_core(ds: FederatedDataset, sim: SimConfig,
         # decision layer (repro/fl/decision.py) — the exact function the
         # scheduler service serves online, which is what the service's
         # bitwise-parity contract rests on.
-        gains, ch_state = channel_obs(channel_step, k_ch, ch_state)
-        sel, q, p, t_comm, power, n_sel, pol_state = decision(
-            policy_step, acct, k_sel, gains, pol_state)
-        # pick up to m_cap participants (nonzero packs left)
-        sel_idx, sel_valid = pack_participants(sel, m_cap)
-        q_sel = q[sel_idx]
-        imgs, labs = sample_batches(k_bat, ds.client_images,
-                                    ds.client_labels, sel_idx, m_cap,
-                                    sim.local_steps, sim.batch)
-        if sharded_update is not None:
-            new_params = sharded_update(params, imgs, labs, sel_valid,
-                                        q_sel)
-        else:
-            # lax.map, not vmap: vmapped convs over per-client weights
-            # lower to grouped convolutions (~30x slower on XLA:CPU).
-            updated = jax.lax.map(
-                lambda b: local_sgd(spec.loss_fn, params, b, sim.gamma,
-                                    sim.local_steps), (imgs, labs))
-            new_params = masked_aggregate(params, updated, sel_valid,
-                                          q_sel, n, sim.aggregation, wire)
+        with jax.named_scope("fl.decision"):
+            gains, ch_state = channel_obs(channel_step, k_ch, ch_state)
+            sel, q, p, t_comm, power, n_sel, pol_state = decision(
+                policy_step, acct, k_sel, gains, pol_state)
+        with jax.named_scope("fl.update"):
+            # pick up to m_cap participants (nonzero packs left)
+            sel_idx, sel_valid = pack_participants(sel, m_cap)
+            q_sel = q[sel_idx]
+            imgs, labs = sample_batches(k_bat, ds.client_images,
+                                        ds.client_labels, sel_idx, m_cap,
+                                        sim.local_steps, sim.batch)
+            if sharded_update is not None:
+                new_params = sharded_update(params, imgs, labs, sel_valid,
+                                            q_sel)
+            else:
+                # lax.map, not vmap: vmapped convs over per-client weights
+                # lower to grouped convolutions (~30x slower on XLA:CPU).
+                updated = jax.lax.map(
+                    lambda b: local_sgd(spec.loss_fn, params, b, sim.gamma,
+                                        sim.local_steps), (imgs, labs))
+                new_params = masked_aggregate(params, updated, sel_valid,
+                                              q_sel, n, sim.aggregation,
+                                              wire)
         return (new_params, pol_state, ch_state, t_comm, power, n_sel)
 
     return round_core
@@ -381,7 +392,9 @@ def scan_chunk(sim_round, eval_fn, carry, n_rounds: int):
         return (params, pst, cst, key, t_cum + t_comm, p_cum + power), nsel
 
     carry, nsel = jax.lax.scan(body, carry, None, length=n_rounds)
-    return carry, eval_fn(carry[0]), nsel[-1]
+    with jax.named_scope("fl.eval"):
+        acc = eval_fn(carry[0])
+    return carry, acc, nsel[-1]
 
 
 def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
@@ -430,7 +443,8 @@ def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
         fresh = ei.compiles.miss(("run_chunk", n_rounds),
                                  entry="run_chunk", n_rounds=n_rounds)
         t0 = perf()
-        carry, acc, nsel = _run_chunk(carry, co_host, ds, n_rounds)
+        with span("fl.dispatch"):
+            carry, acc, nsel = _run_chunk(carry, co_host, ds, n_rounds)
         if fresh:
             # jit traces + compiles synchronously at call time
             ei.compiles.compile_s.inc(perf() - t0)

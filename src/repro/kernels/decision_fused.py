@@ -215,6 +215,7 @@ def decision_fused(gains: jax.Array, z: jax.Array, u: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((n_pad,), jnp.bool_)]
         + [jax.ShapeDtypeStruct((n_pad,), jnp.float32)] * 5,
         interpret=interpret,
+        name="decision_fused",
     )(ops, *lanes)
     return tuple(o[:n_real] for o in outs)
 
@@ -275,5 +276,6 @@ def decision_fused_batched(gains: jax.Array, z: jax.Array, u: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((b_pad, n_pad), jnp.bool_)]
         + [jax.ShapeDtypeStruct((b_pad, n_pad), jnp.float32)] * 5,
         interpret=interpret,
+        name="decision_fused_batched",
     )(ops, *lanes)
     return tuple(o[:b, :n_real] for o in outs)

@@ -136,5 +136,6 @@ def scheduler_solve(gains: jax.Array, z: jax.Array, *, n: int, v: float,
         out_shape=[jax.ShapeDtypeStruct(gains_p.shape, jnp.float32),
                    jax.ShapeDtypeStruct(gains_p.shape, jnp.float32)],
         interpret=interpret,
+        name="scheduler_solve",
     )(gains_p, z_p)
     return q[:n_real], p[:n_real]

@@ -6,7 +6,8 @@ serving machinery around it (flush latency segments, recompile tracking,
 tenant lifecycle, replay-log growth). The contract that makes it safe to
 thread through every hot path: ALL recording is host-side, outside jit —
 telemetry-on runs are bitwise-equal to telemetry-off runs
-(tests/test_obs.py).
+(tests/test_obs.py). Program spans (:func:`span`, ``obs/profile.py``)
+are profiler annotations, recorded whether telemetry is on or off.
 
 Quickstart::
 
@@ -24,12 +25,12 @@ from repro.obs.instrument import (CompileTracker, EngineInstruments,
 from repro.obs.metrics import (NOOP, Counter, Gauge, Histogram,
                                MetricsRegistry, configure, default_registry,
                                enabled, new_registry)
-from repro.obs.profile import trace_span
+from repro.obs.profile import span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NOOP",
     "configure", "default_registry", "enabled", "new_registry",
     "CompileTracker", "EngineInstruments", "ServiceInstruments",
     "TournamentInstruments", "noop_instruments",
-    "EventLog", "json_snapshot", "prometheus_text", "trace_span",
+    "EventLog", "json_snapshot", "prometheus_text", "span",
 ]

@@ -1,33 +1,21 @@
-"""Optional ``jax.profiler`` trace annotations around flush waves.
+"""Program spans on the profiler's clock.
 
-For the deep dives the metric counters cannot answer ("WHAT inside this
-458 ms flush was compile vs dispatch vs device compute"), the service can
-annotate each flush wave with a named ``jax.profiler.TraceAnnotation`` so
-a captured trace (``jax.profiler.start_trace`` -> TensorBoard) shows the
-serve groups as labelled spans.
+:func:`span` is ``jax.profiler.TraceAnnotation``: a named host span that
+a captured trace (``jax.profiler.start_trace``) holds on the host plane,
+on the same clock as the device's operations. It is never gated on the
+telemetry switch, so every trace holds the program's spans; with no
+profiler session a span costs well under a microsecond.
 
-Annotations cost a call into the profiler even when no trace is being
-captured, so :func:`trace_span` is a no-op unless process-wide telemetry
-is on (``repro.obs.configure(True)``) — the hot path pays one bool check.
+    >>> with span("service.pull", flush=3):
+    ...     np.asarray(x)
+
+Keyword metadata (``flush=3``) is encoded into the event (``name#k=v#``);
+readers key on the name before any ``#``. Spans of one flush carry its
+ordinal as ``flush``.
 """
 
 from __future__ import annotations
 
-import contextlib
+from jax.profiler import TraceAnnotation as span
 
-from jax.profiler import TraceAnnotation
-
-from repro.obs import metrics
-
-_NULL = contextlib.nullcontext()
-
-
-def trace_span(name: str):
-    """Context manager: a named profiler span when telemetry is enabled.
-
-    >>> with trace_span("service.flush/wave0"):
-    ...     dispatch_group(...)
-    """
-    if not metrics.enabled():
-        return _NULL
-    return TraceAnnotation(name)
+__all__ = ["span"]

@@ -49,6 +49,15 @@ serving path pays (the PR-8 silent-recompile pathology, made visible;
 recording is host-side, outside jit, which keeps telemetry-on serving
 and replay bitwise-identical to telemetry-off (tests/test_obs.py).
 ``metrics_snapshot()`` exports dict / JSON / Prometheus text.
+
+Program spans (``repro.obs.span``, on the profiler's clock, recorded with
+telemetry on or off): ``service.submit`` per request; per flush the root
+``service.flush``, and inside it per serve group ``service.stage``,
+``service.dispatch``, one ``service.pull`` per device-to-host transfer
+(six a group) and ``service.unpack``, and ``service.log`` around each
+replay-log append. Every span of one flush carries its ordinal
+(``flush=<n>``); a request's ``service.submit`` carries the ordinal of
+the flush that will serve it.
 """
 
 from __future__ import annotations
@@ -70,7 +79,7 @@ from repro.fl.client_shard import POLICY_RAW_PAD
 from repro.obs import metrics as obs_metrics
 from repro.obs.export import EventLog, json_snapshot, prometheus_text
 from repro.obs.instrument import ServiceInstruments, perf
-from repro.obs.profile import trace_span
+from repro.obs.profile import span
 from repro.service.replay import LoggedRequest, RequestLog
 from repro.service.state import (BucketKey, TenantSpec, TenantStore,
                                  bucket_width)
@@ -107,6 +116,14 @@ class _RawProto(NamedTuple):
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length()
+
+
+def _pull(x, fl: int) -> np.ndarray:
+    """One blocking device-to-host transfer of flush ``fl``, in a span of
+    its own (the count of ``service.pull`` spans is the count of
+    transfers)."""
+    with span("service.pull", flush=fl):
+        return np.asarray(x)
 
 
 def _pad_lane(x: np.ndarray, width: int, fill) -> np.ndarray:
@@ -285,6 +302,7 @@ class SchedulerService:
         self._tick = 0
         self._last_used: Dict[str, int] = {}
         self._bstrs: Dict[BucketKey, str] = {}   # cached as_string() forms
+        self._flush_seq = 0   # ordinal of the next flush (span metadata)
 
     # ------------------------------------------------------------ tenants
     def add_tenant(self, name: str, scfg: SchedulerConfig,
@@ -356,6 +374,10 @@ class SchedulerService:
         engines use) must be given. Submitting to an evicted tenant
         reloads it first.
         """
+        with span("service.submit", flush=self._flush_seq):
+            self._submit(name, gains, raw, key)
+
+    def _submit(self, name: str, gains, raw, key) -> None:
         if name in self._spilled:
             self.reload(name)
         spec = self.store.spec(name)
@@ -418,27 +440,27 @@ class SchedulerService:
         replay from the last snapshot reproduces the live state bit for
         bit even across the failure.
         """
+        fl = self._flush_seq
+        self._flush_seq += 1
+        with span("service.flush", flush=fl):
+            return self._flush(log, fl)
+
+    def _flush(self, log: bool, fl: int) -> Dict[str, Decision]:
         obs = self.obs
         t_start = perf()
         if obs.enabled:
             obs.queue_depth.set(self.n_queued)
-        annotate = obs_metrics.enabled()   # profiler spans: global switch
         waves, self._waves = self._waves, []
         pending = []
         try:
-            for wi, w in enumerate(waves):
+            for w in waves:
                 for bkey, reqs in w.groups.items():
-                    if annotate:
-                        with trace_span("service.flush/wave"
-                                        f"{wi}/{self._bucket_str(bkey)}"):
-                            outs = self._dispatch_group(
-                                bkey, reqs, w.stages.get(bkey))
-                    else:
-                        outs = self._dispatch_group(bkey, reqs,
-                                                    w.stages.get(bkey))
+                    outs = self._dispatch_group(bkey, reqs,
+                                                w.stages.get(bkey), fl)
                     if log and self.log_requests:
-                        self.log.append_entry(
-                            [LoggedRequest(*r) for r in reqs])
+                        with span("service.log", flush=fl):
+                            self.log.append_entry(
+                                [LoggedRequest(*r) for r in reqs])
                     pending.append((reqs, outs))
         finally:
             for w in waves:
@@ -448,18 +470,17 @@ class SchedulerService:
         t_pull = perf()
         responses: Dict[str, Decision] = {}
         rec_t_comm = obs.t_comm.record if obs.enabled else None
-        for reqs, (sel, q, p, t_comm, power, n_sel) in pending:
-            sel, q, p = np.asarray(sel), np.asarray(q), np.asarray(p)
-            t_comm, power = np.asarray(t_comm), np.asarray(power)
-            n_sel = np.asarray(n_sel)
-            for i, r in enumerate(reqs):
-                n = self.store.spec(r.tenant).n
-                responses[r.tenant] = Decision(
-                    sel=sel[i, :n], q=q[i, :n], p=p[i, :n],
-                    t_comm=t_comm[i], power=power[i],
-                    n_sel=np.int64(n_sel[i]))
-                if rec_t_comm is not None:
-                    rec_t_comm(float(t_comm[i]))
+        for reqs, outs in pending:
+            sel, q, p, t_comm, power, n_sel = [_pull(x, fl) for x in outs]
+            with span("service.unpack", flush=fl):
+                for i, r in enumerate(reqs):
+                    n = self.store.spec(r.tenant).n
+                    responses[r.tenant] = Decision(
+                        sel=sel[i, :n], q=q[i, :n], p=p[i, :n],
+                        t_comm=t_comm[i], power=power[i],
+                        n_sel=np.int64(n_sel[i]))
+                    if rec_t_comm is not None:
+                        rec_t_comm(float(t_comm[i]))
         t_end = perf()
         obs.pull_s.record(t_end - t_pull)
         obs.flush_s.record(t_end - t_start)
@@ -556,29 +577,31 @@ class SchedulerService:
                              block=min(1024, bkey.n_bucket))
 
     def _dispatch_group(self, bkey: BucketKey, reqs: List[_Pending],
-                        stage: Optional[_Stage]):
-        """Dispatch one (wave, bucket) group; returns device outputs
-        WITHOUT pulling them (async — the next group's host staging
-        overlaps this group's device compute)."""
+                        stage: Optional[_Stage], fl: int):
+        """Dispatch one (wave, bucket) group of flush ``fl``; returns
+        device outputs WITHOUT pulling them (async — the next group's host
+        staging overlaps this group's device compute)."""
         obs = self.obs
         bucket = self.store.buckets()[bkey]
         step = self._bucket_step(bkey, bucket)
         b_pad = _next_pow2(len(reqs))
         row_ids = [self.store.row(r.tenant) for r in reqs]
         t0 = perf()
-        if stage is not None:
-            rows, gains, raw = stage.batch(row_ids, bucket.size, b_pad)
-        else:
-            rows, gains, raw = self._legacy_batch(bkey, bucket, reqs,
-                                                  row_ids, b_pad)
+        with span("service.stage", flush=fl):
+            if stage is not None:
+                rows, gains, raw = stage.batch(row_ids, bucket.size, b_pad)
+            else:
+                rows, gains, raw = self._legacy_batch(bkey, bucket, reqs,
+                                                      row_ids, b_pad)
         t1 = perf()
-        fresh = obs.compiles.miss(
-            step_signature(bkey, bucket.size, b_pad, self.solver),
-            bucket=self._bucket_str(bkey), batch=b_pad,
-            solver=self.solver)
-        sel, q, p, t_comm, power, n_sel, new_state = step(
-            bucket.state, bucket.coeffs, bucket.acct, bucket.n_real,
-            rows, gains, raw)
+        with span("service.dispatch", flush=fl):
+            fresh = obs.compiles.miss(
+                step_signature(bkey, bucket.size, b_pad, self.solver),
+                bucket=self._bucket_str(bkey), batch=b_pad,
+                solver=self.solver)
+            sel, q, p, t_comm, power, n_sel, new_state = step(
+                bucket.state, bucket.coeffs, bucket.acct, bucket.n_real,
+                rows, gains, raw)
         t2 = perf()
         bucket.state = new_state      # old buffers were donated
         obs.stage_s.record(t1 - t0)

@@ -12,6 +12,7 @@ replay-log growth warning fires exactly once, and the disabled-path
 recorder is cheap enough to leave compiled in (loose micro-check).
 """
 
+import glob
 import json
 import time
 import warnings
@@ -191,12 +192,26 @@ def test_event_log_jsonl_and_once(tmp_path):
     assert lines[1]["x"] == 1
 
 
-def test_trace_span_disabled_and_enabled():
-    with obs.trace_span("x"):                    # off: nullcontext
+def test_trace_span_disabled_and_enabled(tmp_path):
+    """``obs.span`` is not gated on the telemetry switch: a trace holds
+    it with telemetry off and on; with no profiler session it is inert."""
+    with obs.span("x"):                          # no session: records nothing
         pass
-    obs.configure(True)
-    with obs.trace_span("service.flush/wave0"):  # on: profiler span
-        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("service.flush", flush=0):     # telemetry off
+            pass
+        obs.configure(True)
+        with obs.span("service.flush", flush=1):     # telemetry on
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    got = sorted(int(dict(e.stats)["flush"]) for p in pd.planes
+                 for line in p.lines for e in line.events
+                 if e.name.split("#", 1)[0] == "service.flush")
+    assert got == [0, 1]
 
 
 # --------------------------------------------------------------------------
