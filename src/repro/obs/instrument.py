@@ -114,6 +114,8 @@ class ServiceInstruments:
         self.stage_s = h("service_flush_stage_seconds")
         self.dispatch_s = h("service_flush_dispatch_seconds")
         self.pull_s = h("service_flush_pull_seconds")
+        # device-to-host transfers of results: one per serve group
+        self.transfers = c("service_flush_transfers_total")
         self.t_comm = h("service_t_comm_seconds")  # Eq. 8 per decision
         # tenant lifecycle
         self.admits = c("service_tenant_admits_total")

@@ -10,8 +10,10 @@ serves one *group* per (wave, bucket): each group is one ``jit(vmap)``
 bucket step (``repro/service/step.py``) over the arena's padded batch —
 donated state, no per-tenant dispatch. Groups are dispatched back to
 back WITHOUT pulling results (JAX async dispatch), so host-side staging
-and dispatch of group k overlap device compute of group k-1; results are
-pulled once, after every group is in flight.
+and dispatch of group k overlap device compute of group k-1. Each group's
+six outputs come back packed in one array (``step.pack_outputs``), whose
+copy to the host starts as soon as the group is dispatched; the flush
+pulls each group once, after every group is in flight.
 
 The batch row axis pads with sentinel rows (row index = T): the gather
 clamps them onto an arbitrary real tenant's inputs (garbage compute,
@@ -54,7 +56,7 @@ Program spans (``repro.obs.span``, on the profiler's clock, recorded with
 telemetry on or off): ``service.submit`` per request; per flush the root
 ``service.flush``, and inside it per serve group ``service.stage``,
 ``service.dispatch``, one ``service.pull`` per device-to-host transfer
-(six a group) and ``service.unpack``, and ``service.log`` around each
+(one a group) and ``service.unpack``, and ``service.log`` around each
 replay-log append. Every span of one flush carries its ordinal
 (``flush=<n>``); a request's ``service.submit`` carries the ordinal of
 the flush that will serve it.
@@ -83,7 +85,8 @@ from repro.obs.profile import span
 from repro.service.replay import LoggedRequest, RequestLog
 from repro.service.state import (BucketKey, TenantSpec, TenantStore,
                                  bucket_width)
-from repro.service.step import make_bucket_step, step_signature
+from repro.service.step import (make_bucket_step, step_signature,
+                                unpack_outputs)
 
 GAINS_PAD = 0.0  # below every clipped channel gain (gain_bounds lo > 0)
 
@@ -118,10 +121,12 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, int(n) - 1).bit_length()
 
 
-def _pull(x, fl: int) -> np.ndarray:
-    """One blocking device-to-host transfer of flush ``fl``, in a span of
-    its own (the count of ``service.pull`` spans is the count of
-    transfers)."""
+def _pull(x, fl: int, transfers) -> np.ndarray:
+    """A serve group's one device-to-host transfer in flush ``fl``, in a
+    span of its own (the count of ``service.pull`` spans is the count of
+    transfers), counted on ``transfers``. It waits on the copy that
+    dispatch started."""
+    transfers.inc()
     with span("service.pull", flush=fl):
         return np.asarray(x)
 
@@ -455,13 +460,13 @@ class SchedulerService:
         try:
             for w in waves:
                 for bkey, reqs in w.groups.items():
-                    outs = self._dispatch_group(bkey, reqs,
-                                                w.stages.get(bkey), fl)
+                    packed = self._dispatch_group(bkey, reqs,
+                                                  w.stages.get(bkey), fl)
                     if log and self.log_requests:
                         with span("service.log", flush=fl):
                             self.log.append_entry(
                                 [LoggedRequest(*r) for r in reqs])
-                    pending.append((reqs, outs))
+                    pending.append((bkey.n_bucket, reqs, packed))
         finally:
             for w in waves:
                 for bkey, stage in w.stages.items():
@@ -470,9 +475,11 @@ class SchedulerService:
         t_pull = perf()
         responses: Dict[str, Decision] = {}
         rec_t_comm = obs.t_comm.record if obs.enabled else None
-        for reqs, outs in pending:
-            sel, q, p, t_comm, power, n_sel = [_pull(x, fl) for x in outs]
+        for n_bucket, reqs, packed in pending:
+            packed = _pull(packed, fl, obs.transfers)
             with span("service.unpack", flush=fl):
+                sel, q, p, t_comm, power, n_sel = unpack_outputs(packed,
+                                                                 n_bucket)
                 for i, r in enumerate(reqs):
                     n = self.store.spec(r.tenant).n
                     responses[r.tenant] = Decision(
@@ -578,9 +585,10 @@ class SchedulerService:
 
     def _dispatch_group(self, bkey: BucketKey, reqs: List[_Pending],
                         stage: Optional[_Stage], fl: int):
-        """Dispatch one (wave, bucket) group of flush ``fl``; returns
-        device outputs WITHOUT pulling them (async — the next group's host
-        staging overlaps this group's device compute)."""
+        """Dispatch one (wave, bucket) group of flush ``fl``; returns its
+        packed device output WITHOUT pulling it (async — the next group's
+        host staging overlaps this group's device compute and the copy of
+        its output to the host, started here)."""
         obs = self.obs
         bucket = self.store.buckets()[bkey]
         step = self._bucket_step(bkey, bucket)
@@ -599,9 +607,10 @@ class SchedulerService:
                 step_signature(bkey, bucket.size, b_pad, self.solver),
                 bucket=self._bucket_str(bkey), batch=b_pad,
                 solver=self.solver)
-            sel, q, p, t_comm, power, n_sel, new_state = step(
+            packed, new_state = step(
                 bucket.state, bucket.coeffs, bucket.acct, bucket.n_real,
                 rows, gains, raw)
+            packed.copy_to_host_async()
         t2 = perf()
         bucket.state = new_state      # old buffers were donated
         obs.stage_s.record(t1 - t0)
@@ -616,7 +625,7 @@ class SchedulerService:
             waste.record((b_pad - len(reqs)) / b_pad)
             obs.groups.inc()
             obs.requests.inc(len(reqs))
-        return sel, q, p, t_comm, power, n_sel
+        return packed
 
     def _legacy_batch(self, bkey: BucketKey, bucket, reqs, row_ids,
                       b_pad: int):

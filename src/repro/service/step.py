@@ -56,9 +56,11 @@ tenants batch in one program (no homogeneity requirement, unlike
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.channel import ChannelConfig
 from repro.core.policies import PolicyState, fence_step
@@ -157,13 +159,70 @@ def step_signature(bkey, n_tenants: int, batch: int, solver: str) -> tuple:
     return (bkey, int(n_tenants), int(batch), solver)
 
 
+class PackedLayout(NamedTuple):
+    """Columns of one row of a bucket step's packed (B, W) uint32 output:
+    ``sel`` (0/1), ``q`` and ``p`` (float32 bits) over the bucket's lanes,
+    then one column each of ``t_comm``, ``power`` (float32 bits) and
+    ``n_sel`` (int32 bits)."""
+
+    sel: slice
+    q: slice
+    p: slice
+    t_comm: int
+    power: int
+    n_sel: int
+    width: int
+
+
+def packed_layout(n_bucket: int) -> PackedLayout:
+    """The packed output's column layout for a bucket of ``n_bucket``
+    lanes (:func:`pack_outputs` writes it, :func:`unpack_outputs` reads
+    it)."""
+    nb = int(n_bucket)
+    return PackedLayout(sel=slice(0, nb), q=slice(nb, 2 * nb),
+                        p=slice(2 * nb, 3 * nb), t_comm=3 * nb,
+                        power=3 * nb + 1, n_sel=3 * nb + 2, width=3 * nb + 3)
+
+
+def pack_outputs(sel, q, p, t_comm, power, n_sel):
+    """The six per-row decision outputs as one (B, W) uint32 array, in
+    :func:`packed_layout`'s columns, so the host pulls a serve group in
+    one device-to-host transfer. Bitcasts only: every value keeps its bits
+    (-0.0, denormals, inf and NaN payloads included)."""
+    def bits(x):
+        x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        return x if x.ndim == 2 else x[:, None]
+
+    # n_sel counts at most n_bucket lanes: int32 holds it exactly (the
+    # sum is int64 under JAX_ENABLE_X64)
+    return jnp.concatenate(
+        [sel.astype(jnp.uint32), bits(q), bits(p), bits(t_comm),
+         bits(power), bits(n_sel.astype(jnp.int32))], axis=1)
+
+
+def unpack_outputs(packed: np.ndarray, n_bucket: int):
+    """``(sel, q, p, t_comm, power, n_sel)`` of a pulled packed output:
+    views of the one host buffer (bool ``sel`` is the one copy), with the
+    dtypes the step computed them in."""
+    lay = packed_layout(n_bucket)
+    return (packed[:, lay.sel].astype(bool),
+            packed[:, lay.q].view(np.float32),
+            packed[:, lay.p].view(np.float32),
+            packed[:, lay.t_comm].view(np.float32),
+            packed[:, lay.power].view(np.float32),
+            packed[:, lay.n_sel].view(np.int32))
+
+
 def make_bucket_step(policy: str, n_bucket: int, acct_len: int,
                      guarantee_one: bool, solve_fn=None,
                      fused: bool = False):
     """Build the jitted batched serving step for one bucket shape.
 
     Returns ``bucket_step(state, coeffs, acct, n_real, rows, gains, raw)
-    -> (sel, q, p, t_comm, power, n_sel, state')`` where
+    -> (packed, state')`` where ``packed`` is the batch's six decision
+    outputs (sel, q, p, t_comm, power, n_sel) in one (B, W) uint32 array
+    (:func:`pack_outputs`; :func:`unpack_outputs` takes it apart on the
+    host), so a serve group costs one device-to-host transfer, and
 
     * ``state`` — the bucket's stacked :class:`PolicyState` (leaves
       (T, n_bucket) / (T,)). DONATED: the returned state reuses its
@@ -247,6 +306,6 @@ def make_bucket_step(policy: str, n_bucket: int, acct_len: int,
         new_state = jax.tree.map(
             lambda buf, upd: buf.at[rows].set(upd, mode="drop"),
             state, st_new)
-        return sel, q, p, t_comm, power, n_sel, new_state
+        return pack_outputs(sel, q, p, t_comm, power, n_sel), new_state
 
     return bucket_step

@@ -715,3 +715,97 @@ def test_pallas_solver_bucket():
     svc_bad.submit("x", gains, key=key)
     with pytest.raises(ValueError, match="homogeneous"):
         svc_bad.flush()
+
+
+# --------------------------------------------------------------------------
+# One device-to-host transfer a serve group: the packed step output.
+# --------------------------------------------------------------------------
+
+def _as_six(*outs):
+    return outs
+
+
+@pytest.mark.parametrize("n", [40, 100, 3597], ids=["b64", "b128", "b4096"])
+@pytest.mark.parametrize("solver", ["jnp", "pallas", "pallas_fused"])
+def test_packed_pull_serves_the_steps_outputs_bitwise(monkeypatch, solver,
+                                                      n):
+    """Each served Decision is, bit for bit and dtype for dtype, the
+    step's six outputs as the step computed them before packing, and each
+    serve group costs one counted device-to-host transfer."""
+    from repro.service import batching
+    from repro.service import step as step_mod
+
+    groups = []
+    real = batching.make_bucket_step
+
+    def make(*a, **k):
+        step, ref = real(*a, **k), real(*a, **k)
+
+        def recorded(state, *args):
+            # the same step with packing left out, on a copy of the
+            # (donated) state: the six outputs the packed array carries
+            with monkeypatch.context() as m:
+                m.setattr(step_mod, "pack_outputs", _as_six)
+                outs, _ = ref(jax.tree.map(jnp.copy, state), *args)
+            groups.append([np.asarray(x) for x in outs])
+            return step(state, *args)
+        return recorded
+    monkeypatch.setattr(batching, "make_bucket_step", make)
+
+    scfg, ch = _configs(n=n)
+    svc = SchedulerService(solver=solver, telemetry=True)
+    for name in ("a", "b"):
+        svc.add_tenant(name, scfg, ch)
+    rng = np.random.default_rng(n)
+    for t, name in enumerate(("a", "b", "a")):   # waves [a, b] and [a]
+        svc.submit(name, rng.uniform(0.05, 3.0, n).astype(np.float32),
+                   key=jax.random.PRNGKey(t))
+    out = svc.flush()
+    assert len(groups) == 2
+    for name, (g, i) in {"a": (1, 0), "b": (0, 1)}.items():
+        sel, q, p, t_comm, power, n_sel = groups[g]
+        want = (sel[i, :n], q[i, :n], p[i, :n], t_comm[i], power[i],
+                np.int64(n_sel[i]))
+        for field, got, w in zip(out[name]._fields, out[name], want):
+            assert got.dtype == w.dtype, (name, field)
+            assert got.tobytes() == w.tobytes(), (name, field)
+    assert [x.dtype for x in out["a"]] == [
+        np.bool_, np.float32, np.float32, np.float32, np.float32, np.int64]
+    reg = svc.obs.registry
+    assert reg.total("service_flush_transfers_total") == 2
+    assert reg.total("service_groups_served_total") == 2
+
+
+def _f32(*bit_patterns):
+    return np.array(bit_patterns, np.uint32).view(np.float32)
+
+
+@pytest.mark.parametrize("n_bucket", [8, 128, 4096])
+def test_pack_unpack_round_trip_keeps_every_bit(n_bucket):
+    """-0.0, denormals, infinities and NaN payloads (quiet and signalling,
+    either sign) survive the device-side pack and the host-side unpack
+    bit for bit, in every column of the layout."""
+    from repro.service.step import (pack_outputs, packed_layout,
+                                    unpack_outputs)
+
+    special = _f32(0x80000000, 0x00000001, 0x807fffff, 0x7f800000,
+                   0xff800000, 0x7fc00000, 0x7fc12345, 0x7f800001,
+                   0xffbfffff, 0xffffffff)
+    rng = np.random.default_rng(n_bucket)
+    b = 3
+    lanes = rng.integers(0, 2**32, (2, b, n_bucket),
+                         dtype=np.uint32).view(np.float32)
+    lanes[:, :, :special.size] = special[:n_bucket]
+    q, p = lanes
+    t_comm, power = special[:b], special[-b:]
+    sel = rng.random((b, n_bucket)) < 0.5
+    n_sel = np.array([0, n_bucket, 2**31 - 1], np.int32)
+    want = (sel, q, p, t_comm, power, n_sel)
+
+    packed = jax.jit(pack_outputs)(*want)
+    assert packed.dtype == jnp.uint32
+    assert packed.shape == (b, packed_layout(n_bucket).width)
+    got = unpack_outputs(np.asarray(packed), n_bucket)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype and g.shape == w.shape, i
+        assert g.tobytes() == w.tobytes(), i

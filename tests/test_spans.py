@@ -27,7 +27,7 @@ from repro.models.registry import make_model
 from repro.service import SchedulerService
 
 PREFIXES = ("service.", "fl.")
-PULLS_PER_GROUP = 6       # sel, q, p, t_comm, power, n_sel
+PULLS_PER_GROUP = 1       # sel, q, p, t_comm, power, n_sel packed in one
 
 
 class Span(NamedTuple):
@@ -104,8 +104,8 @@ def test_service_flush_spans_nest_and_count_transfers(tmp_path):
     inner = [s for s in spans if s not in submits and s is not root]
     assert all(root.start <= s.start and s.end <= root.end for s in inner)
     # per group: stage, dispatch and the log append, both groups in
-    # flight before any pull; then per group its six transfers, each in
-    # a span of its own, and the building of its decisions
+    # flight before any pull; then per group its one transfer, in a span
+    # of its own, and the building of its decisions
     group_out = ["service.pull"] * PULLS_PER_GROUP + ["service.unpack"]
     assert [s.name for s in inner] == (
         ["service.stage", "service.dispatch", "service.log"] * 2
