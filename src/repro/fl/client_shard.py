@@ -49,6 +49,8 @@ sharded form yet and are rejected up front.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,8 +67,12 @@ from repro.core.scheduler import (coeff_rate, greedy_coeffs,
                                   uniform_draw_m, update_queues_z)
 from repro.fl.decision import (DecisionCoeffs, channel_obs, decision_coeffs,
                                decision_step)
+from repro.fl.round import pack_participants
 from repro.fl.sharding import (ACCOUNT_BLOCKS, blocked_total_sharded,
                                pad_client_axis, padded_len, shard_map)
+from repro.obs import metrics as obs_metrics
+from repro.obs.instrument import EngineInstruments
+from repro.obs.profile import span
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -556,22 +562,172 @@ def draw_policy_raw(policy: str, key, n: int):
 
 
 # --------------------------------------------------------------------------
-# Scheduling-only trajectory runner: the massive-N bench/demo driver.
+# Scheduling-only runner: the massive-N path, stepped chunk by chunk.
 # --------------------------------------------------------------------------
 
-def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
-                         ch: ChannelConfig, *, rounds: int,
-                         policy: str = "proposed", m_avg: float = 0.0,
-                         channel: str = "rayleigh",
-                         channel_params: tuple = (), solver: str = "jnp",
-                         client_shards: int = 0, m_cap: int = 32,
-                         solve_fn=None, devices=None):
-    """Jitted scheduling-layer trajectory (no model training, no dataset).
+def _fresh_states(chan, policy: str, n: int, key):
+    """Empty queues and the channel's stationary init off
+    ``fold_in(key, CHANNEL_INIT_TAG)`` — the engines' side channel, so the
+    round-key chain is the scan engine's."""
+    from repro.fl.engine import CHANNEL_INIT_TAG
 
-    ``runner(key) -> (t_comm, power, n_sel)``, each (rounds,): per-round
-    TDMA communication time, sum P q, and participation count — the
-    massive-N hot path alone, which is what ``bench_massive`` times and
-    ``examples/massive_n.py`` demonstrates at N = 10^5..10^6.
+    return (init_policy_state(policy, n),
+            chan.init(jax.random.fold_in(key, CHANNEL_INIT_TAG)))
+
+
+def init_schedule_carry(key, sigmas: jax.Array, ch: ChannelConfig, *,
+                        policy: str = "proposed", channel: str = "rayleigh",
+                        channel_params: tuple = ()):
+    """A fresh ``(pol_state, ch_state, key)`` carry for
+    :func:`make_schedule_chunk_runner` (:func:`_fresh_states`). The carry
+    holds its own copy of ``key`` (chunks donate their carry)."""
+    chan = make_channel(channel, sigmas, ch, **dict(channel_params))
+    states = jax.jit(functools.partial(_fresh_states, chan, policy,
+                                       int(sigmas.shape[0])))
+    return (*states(key), jnp.array(key, copy=True))
+
+
+def _schedule_rows(t_comm, power, n_sel, overflow, ids):
+    """A chunk's output, ONE uint32 array: per round t_comm and power as
+    their float32 bits, n_sel, overflow, then the packed ids."""
+    u32 = jnp.uint32
+    head = jnp.stack([jax.lax.bitcast_convert_type(t_comm, u32),
+                      jax.lax.bitcast_convert_type(power, u32),
+                      n_sel.astype(u32), overflow.astype(u32)], axis=1)
+    return jnp.concatenate([head, ids.astype(u32)], axis=1)
+
+
+class ScheduleChunks:
+    """``run_chunk(carry, n_rounds) -> (carry, rows)``: ``n_rounds``
+    scheduling rounds in one jitted call (``n_rounds`` static, the carry
+    donated), as :func:`make_schedule_chunk_runner` builds it.
+
+    ``rows`` is ONE (n_rounds, 4 + m_cap) uint32 array, so a chunk's
+    results cross to the host in one transfer; :meth:`unpack` reads it on
+    the host. The decision coefficients go to the device once, here. A
+    chunk call records the ``fl.dispatch`` span, and each new chunk length
+    counts an ``engine_compile_misses_total`` miss, as the scan engine's
+    chunk runner does.
+    """
+
+    def __init__(self, scan_rounds, co: DecisionCoeffs):
+        def chunk(carry, co, n_rounds):
+            carry, outs = scan_rounds(carry, co, n_rounds)
+            with jax.named_scope("fl.pack"):
+                return carry, _schedule_rows(*outs)
+
+        self._jit = jax.jit(chunk, static_argnames=("n_rounds",),
+                            donate_argnums=(0,))
+        self.co = jax.device_put(co)
+        self._ei = EngineInstruments(obs_metrics.default_registry())
+
+    def __call__(self, carry, n_rounds: int):
+        self._ei.compiles.miss(("schedule_chunk", n_rounds),
+                               entry="schedule_chunk", n_rounds=n_rounds)
+        with span("fl.dispatch"):
+            return self._jit(carry, self.co, n_rounds=n_rounds)
+
+    def lower(self, carry, n_rounds: int):
+        """The chunk program for ``n_rounds``, lowered (not run)."""
+        return self._jit.lower(carry, self.co, n_rounds=n_rounds)
+
+    def unpack(self, rows) -> dict:
+        """A chunk's rows on the host: per round ``t_comm``, ``power``
+        (float32), ``n_sel``, ``overflow`` (the selected clients past
+        ``m_cap``) and ``ids`` (n_rounds, m_cap), the selected clients
+        ascending, zero-filled past ``min(n_sel, m_cap)``. Adds the
+        overflow to ``fl_schedule_overflow_total``."""
+        rows = np.asarray(rows)
+        out = dict(t_comm=rows[:, 0].view(np.float32),
+                   power=rows[:, 1].view(np.float32),
+                   n_sel=rows[:, 2].astype(np.int32),
+                   overflow=rows[:, 3].astype(np.int32),
+                   ids=rows[:, 4:].astype(np.int32))
+        self._ei.schedule_overflow.inc(int(out["overflow"].sum()))
+        return out
+
+
+def _schedule_rounds(sigmas: jax.Array, scfg: SchedulerConfig,
+                     ch: ChannelConfig, *, policy: str, m_avg: float,
+                     channel: str, channel_params: tuple, solver: str,
+                     client_shards: int, m_cap: int, solve_fn, devices):
+    """``scan_rounds(carry, co, n_rounds) -> (carry, (t_comm, power, n_sel,
+    overflow, ids))``, each stacked over the rounds: the one scheduling
+    round body both runners scan (see :func:`make_schedule_chunk_runner`).
+    The ids and the overflow are separate outputs, so a caller that drops
+    them drops the pack with them."""
+    from repro.fl.engine import resolve_solve_fn
+
+    n = int(sigmas.shape[0])
+    solve = resolve_solve_fn(scfg, ch, solver, solve_fn)
+    fused = solver == "pallas_fused" and policy == "proposed"
+    chan = make_channel(channel, sigmas, ch, **dict(channel_params))
+    if client_shards:
+        schedule = make_sharded_schedule(
+            policy, channel, channel_params, scfg, ch, sigmas,
+            n_shards=client_shards, m_cap=m_cap, m_avg=m_avg,
+            solve_fn=solve, devices=devices, fused=fused)
+
+        def round_fn(pol_state, ch_state, k, co):
+            k_ch, k_sel, _ = jax.random.split(k, 3)
+            with jax.named_scope("fl.decision"):
+                raw_ch = draw_channel_raw(channel, k_ch, n,
+                                          dict(channel_params))
+                raw_pol = draw_policy_raw(policy, k_sel, n)
+                (t_comm, power, n_sel, ids, _, _, pol_state,
+                 ch_state) = schedule(raw_ch, raw_pol, pol_state, ch_state,
+                                      co)
+            with jax.named_scope("fl.pack"):
+                overflow = n_sel - jnp.minimum(n_sel, m_cap)
+            return pol_state, ch_state, (t_comm, power, n_sel, overflow, ids)
+    else:
+        def round_fn(pol_state, ch_state, k, co):
+            # the sequential reference IS the shared decision layer (the
+            # same function the scan engine and the service run)
+            step = make_policy(policy, scfg, ch, m_avg=m_avg,
+                               solve_fn=solve, coeffs=co.solve)
+            decision = decision_step
+            if fused:
+                from repro.fl.decision import make_fused_decision
+                decision = make_fused_decision(scfg, co)
+            k_ch, k_sel, _ = jax.random.split(k, 3)
+            with jax.named_scope("fl.decision"):
+                gains, ch_state = channel_obs(chan.step, k_ch, ch_state)
+                sel, q, p, t_comm, power, n_sel, pol_state = decision(
+                    step, co.acct, k_sel, gains, pol_state)
+            with jax.named_scope("fl.pack"):
+                ids, _, overflow = pack_participants(sel, m_cap)
+            return pol_state, ch_state, (t_comm, power, n_sel, overflow, ids)
+
+    def scan_rounds(carry, co, n_rounds):
+        def body(carry, _):
+            pst, cst, k = carry
+            k, kr = jax.random.split(k)
+            pst, cst, out = round_fn(pst, cst, kr, co)
+            return (pst, cst, k), out
+
+        return jax.lax.scan(body, carry, None, length=n_rounds)
+
+    return scan_rounds
+
+
+def make_schedule_chunk_runner(sigmas: jax.Array, scfg: SchedulerConfig,
+                               ch: ChannelConfig, *,
+                               policy: str = "proposed", m_avg: float = 0.0,
+                               channel: str = "rayleigh",
+                               channel_params: tuple = (),
+                               solver: str = "jnp", client_shards: int = 0,
+                               m_cap: int = 32, solve_fn=None,
+                               devices=None) -> ScheduleChunks:
+    """The scheduling layer alone (no model training, no dataset), stepped:
+    ``run_chunk(carry, n_rounds) -> (carry, rows)`` from a carry of
+    :func:`init_schedule_carry`, so the Eq. 9 queues carry from one call
+    to the next and a fleet is scheduled round after round.
+
+    Each round's row holds its TDMA communication time, sum P q, the
+    participation count, the selected clients that did not fit in
+    ``m_cap`` slots, and the first ``m_cap`` selected client ids,
+    ascending (:meth:`ScheduleChunks.unpack`).
 
     ``client_shards=0`` is the sequential reference: the SAME per-round key
     chain and the same blocked accounting reduce, driven through the
@@ -585,62 +741,54 @@ def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
     sequential decision in one kernel pass, or one pass per shard slice —
     bitwise-equal to the stitched paths, so the sequential-vs-sharded
     comparison above is unchanged.
+
+    Device operations carry the named scopes ``fl.decision`` (channel
+    observation and decision; in the sharded branch the merged index pack
+    too) and ``fl.pack`` (the id pack and the output rows).
     """
-    from repro.fl.engine import resolve_solve_fn
+    return ScheduleChunks(
+        _schedule_rounds(sigmas, scfg, ch, policy=policy, m_avg=m_avg,
+                         channel=channel, channel_params=channel_params,
+                         solver=solver, client_shards=client_shards,
+                         m_cap=m_cap, solve_fn=solve_fn, devices=devices),
+        decision_coeffs(scfg, ch))
 
-    n = int(sigmas.shape[0])
-    solve = resolve_solve_fn(scfg, ch, solver, solve_fn)
-    fused = solver == "pallas_fused" and policy == "proposed"
+
+def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
+                         ch: ChannelConfig, *, rounds: int,
+                         policy: str = "proposed", m_avg: float = 0.0,
+                         channel: str = "rayleigh",
+                         channel_params: tuple = (), solver: str = "jnp",
+                         client_shards: int = 0, m_cap: int = 32,
+                         solve_fn=None, devices=None):
+    """A whole scheduling-layer trajectory from fresh queues.
+
+    ``runner(key) -> (t_comm, power, n_sel)``, each (rounds,):
+    per-round TDMA communication time, sum P q, and participation count —
+    the massive-N hot path alone, which is what ``bench_massive`` times and
+    ``examples/massive_n.py`` demonstrates at N = 10^5..10^6. It is one
+    jitted program: the carry of :func:`init_schedule_carry` and the rounds
+    of :func:`make_schedule_chunk_runner` (see there for ``client_shards``,
+    ``solver`` and ``m_cap``), without the ids, so the compiler drops the
+    id pack.
+    """
+    scan_rounds = _schedule_rounds(
+        sigmas, scfg, ch, policy=policy, m_avg=m_avg, channel=channel,
+        channel_params=channel_params, solver=solver,
+        client_shards=client_shards, m_cap=m_cap, solve_fn=solve_fn,
+        devices=devices)
     chan = make_channel(channel, sigmas, ch, **dict(channel_params))
-    co_host = decision_coeffs(scfg, ch)
-    if client_shards:
-        schedule = make_sharded_schedule(
-            policy, channel, channel_params, scfg, ch, sigmas,
-            n_shards=client_shards, m_cap=m_cap, m_avg=m_avg,
-            solve_fn=solve, devices=devices, fused=fused)
-
-        def round_fn(pol_state, ch_state, k, co):
-            k_ch, k_sel, _ = jax.random.split(k, 3)
-            raw_ch = draw_channel_raw(channel, k_ch, n,
-                                      dict(channel_params))
-            raw_pol = draw_policy_raw(policy, k_sel, n)
-            (t_comm, power, n_sel, _, _, _, pol_state,
-             ch_state) = schedule(raw_ch, raw_pol, pol_state, ch_state, co)
-            return pol_state, ch_state, t_comm, power, n_sel
-    else:
-        def round_fn(pol_state, ch_state, k, co):
-            # the sequential reference IS the shared decision layer (the
-            # same function the scan engine and the service run)
-            step = make_policy(policy, scfg, ch, m_avg=m_avg,
-                               solve_fn=solve, coeffs=co.solve)
-            decision = decision_step
-            if fused:
-                from repro.fl.decision import make_fused_decision
-                decision = make_fused_decision(scfg, co)
-            k_ch, k_sel, _ = jax.random.split(k, 3)
-            gains, ch_state = channel_obs(chan.step, k_ch, ch_state)
-            sel, q, p, t_comm, power, n_sel, pol_state = decision(
-                step, co.acct, k_sel, gains, pol_state)
-            return pol_state, ch_state, t_comm, power, n_sel
-
-    from repro.fl.engine import CHANNEL_INIT_TAG
+    n = int(sigmas.shape[0])
+    co = jax.device_put(decision_coeffs(scfg, ch))
 
     @jax.jit
     def _runner(key, co):
-        cst0 = chan.init(jax.random.fold_in(key, CHANNEL_INIT_TAG))
-        pst0 = init_policy_state(policy, n)
-
-        def body(carry, _):
-            pst, cst, k = carry
-            k, kr = jax.random.split(k)
-            pst, cst, t_comm, power, n_sel = round_fn(pst, cst, kr, co)
-            return (pst, cst, k), (t_comm, power, n_sel)
-
-        _, out = jax.lax.scan(body, (pst0, cst0, key), None, length=rounds)
-        return out
+        carry = (*_fresh_states(chan, policy, n, key), key)
+        _, (t_comm, power, n_sel, _, _) = scan_rounds(carry, co, rounds)
+        return t_comm, power, n_sel
 
     def runner(key):
-        return _runner(key, co_host)
+        return _runner(key, co)
 
     return runner
 

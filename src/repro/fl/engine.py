@@ -256,7 +256,7 @@ def make_round_core(ds: FederatedDataset, sim: SimConfig,
                 policy_step, acct, k_sel, gains, pol_state)
         with jax.named_scope("fl.update"):
             # pick up to m_cap participants (nonzero packs left)
-            sel_idx, sel_valid = pack_participants(sel, m_cap)
+            sel_idx, sel_valid, _ = pack_participants(sel, m_cap)
             q_sel = q[sel_idx]
             imgs, labs = sample_batches(k_bat, ds.client_images,
                                         ds.client_labels, sel_idx, m_cap,

@@ -210,7 +210,7 @@ def make_population_core(ds: FederatedDataset, sim, scfg: SchedulerConfig,
         # stragglers: selected-but-failed devices burned their TDMA slot
         # (t_comm and n_sel keep them) but deliver nothing downstream
         delivered, _failed = failure_split(fail_raw, sel, pcfg)
-        sel_idx, sel_valid = pack_participants(delivered, m_cap)
+        sel_idx, sel_valid, _ = pack_participants(delivered, m_cap)
         q_sel = q[sel_idx]
         imgs, labs = sample_batches(k_bat, ds.client_images,
                                     ds.client_labels, sel_idx, m_cap,

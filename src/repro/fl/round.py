@@ -118,15 +118,18 @@ def fl_round(loss_fn: Callable, params, client_batches, selected, q,
 def pack_participants(sel, m_cap: int):
     """Pack the first ``m_cap`` selected clients to the front.
 
-    ``sel`` is the (N,) selection mask; returns ``(sel_idx, sel_valid)`` —
-    the packed (ascending) client indices, zero-filled past the selection
-    count, and the validity mask. The single-device home of the packing the
-    client-sharded engine reproduces with a per-shard pack + cross-shard
-    merge (``fl/client_shard.py::_pack_participants_sharded``).
+    ``sel`` is the (N,) selection mask; returns ``(sel_idx, sel_valid,
+    overflow)`` — the packed (ascending) client indices, zero-filled past
+    the selection count, the validity mask, and the int32 count of
+    selected clients that did not fit. The single-device home of the
+    packing the client-sharded engine reproduces with a per-shard pack +
+    cross-shard merge (``fl/client_shard.py::_pack_participants_sharded``).
     """
+    n_sel = jnp.sum(sel)
     sel_idx = jnp.nonzero(sel, size=m_cap, fill_value=0)[0]
-    sel_valid = jnp.arange(m_cap) < jnp.sum(sel)
-    return sel_idx, sel_valid
+    sel_valid = jnp.arange(m_cap) < n_sel
+    overflow = (n_sel - jnp.minimum(n_sel, m_cap)).astype(jnp.int32)
+    return sel_idx, sel_valid, overflow
 
 
 def sample_batches(key, client_images, client_labels, sel_idx, m_cap: int,

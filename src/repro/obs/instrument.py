@@ -169,6 +169,8 @@ class EngineInstruments:
                             edges=OCCUPANCY_EDGES)  # q feasibility
         self.z_mean = g("engine_z_mean")            # Eq. 9 virtual queues
         self.z_max = g("engine_z_max")
+        # selected clients past the scheduling runner's id slots
+        self.schedule_overflow = c("fl_schedule_overflow_total")
         self.compiles = CompileTracker(registry, "engine")
 
     def record_history(self, hist: dict, wall: float) -> None:

@@ -12,6 +12,7 @@ from repro.core import ChannelConfig, SchedulerConfig, heterogeneous_sigmas
 from repro.data.synthetic import make_cifar10_like, make_lm_federated
 from repro.fl.engine import (SimConfig, eval_rounds, history_from_trajectory,
                              make_solve_fn, run_simulation_scan, run_sweep)
+from repro.fl.round import pack_participants
 from repro.fl.simulation import run_simulation, run_simulation_loop
 from repro.models.cnn import CNNConfig, init_cnn
 from repro.models.registry import make_model
@@ -257,3 +258,16 @@ def test_run_sweep_registry_policies_and_channels(small_setup):
     assert abs(sw["n_selected"][2].mean() - round(m)) < 1.0
     # aoi's forced picks can exceed m when many clients hit the cap
     assert sw["n_selected"][5].mean() >= round(m) - 1.0
+
+
+def test_pack_participants_overflow():
+    """The round's pack keeps the first ``m_cap`` selected clients and
+    counts the rest."""
+    sel = np.zeros(20, bool)
+    sel[[2, 3, 7, 11, 19]] = True
+    idx, valid, overflow = pack_participants(sel, 3)
+    np.testing.assert_array_equal(idx, [2, 3, 7])
+    assert valid.all() and int(overflow) == 2 and overflow.dtype == np.int32
+    idx, valid, overflow = pack_participants(sel, 8)
+    np.testing.assert_array_equal(idx, [2, 3, 7, 11, 19, 0, 0, 0])
+    assert valid.sum() == 5 and int(overflow) == 0

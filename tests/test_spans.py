@@ -1,10 +1,12 @@
 """Program spans and named scopes, read back from CPU profiler traces.
 
-The service's flush and the engine's chunk call record ``repro.obs.span``
-host spans whether telemetry is on or off; the engine round's device
-operations carry the named scopes ``fl.decision``, ``fl.update`` and
-``fl.eval`` in their HLO ``op_name`` metadata. A benchmark reduces both
-from a device trace, so their names, nesting and counts are pinned here.
+The service's flush and the engine's and the scheduling runner's chunk
+calls record ``repro.obs.span`` host spans whether telemetry is on or
+off; the engine round's device operations carry the named scopes
+``fl.decision``, ``fl.update`` and ``fl.eval`` in their HLO ``op_name``
+metadata, the scheduling runner's ``fl.decision`` and ``fl.pack``. A
+benchmark reduces both from a device trace, so their names, nesting and
+counts are pinned here.
 """
 
 import contextlib
@@ -20,6 +22,8 @@ import pytest
 from repro.core import ChannelConfig, SchedulerConfig, heterogeneous_sigmas
 from repro.core.policies import POLICY_DRAWS
 from repro.data.synthetic import make_cifar10_like
+from repro.fl.client_shard import (init_schedule_carry,
+                                   make_schedule_chunk_runner)
 from repro.fl.decision import decision_coeffs
 from repro.fl.engine import (SimConfig, init_carry, make_chunk_runner,
                              make_eval_fn, make_sim_round, scan_chunk)
@@ -170,3 +174,36 @@ def test_round_scopes_in_op_name_metadata(chunk_hlo, scope):
     """The compiled chunk's operations name their part of the round in
     ``op_name``, the metadata a device trace carries per operation."""
     assert re.search(rf'op_name="([^"]*/)?{re.escape(scope)}/', chunk_hlo)
+
+
+def _tiny_schedule():
+    n = 300
+    sig = heterogeneous_sigmas(n)
+    ch = ChannelConfig(n_clients=n)
+    run_chunk = make_schedule_chunk_runner(
+        sig, SchedulerConfig(n_clients=n, model_bits=1e5), ch, m_cap=16)
+    return run_chunk, init_schedule_carry(jax.random.PRNGKey(3), sig, ch)
+
+
+def test_schedule_chunk_call_is_one_dispatch_span(tmp_path):
+    run_chunk, carry = _tiny_schedule()
+    carry, out = run_chunk(carry, 2)             # compiles
+    jax.block_until_ready(out)
+    with _traced(tmp_path):
+        for _ in range(3):
+            carry, out = run_chunk(carry, 2)
+            run_chunk.unpack(out)
+    assert [s.name for s in _program_spans(tmp_path)] == ["fl.dispatch"] * 3
+
+
+@pytest.fixture(scope="module")
+def schedule_hlo():
+    """The compiled text of one scheduling-runner chunk."""
+    run_chunk, carry = _tiny_schedule()
+    return run_chunk.lower(carry, 1).compile().as_text()
+
+
+@pytest.mark.parametrize("scope", ["fl.decision", "fl.pack"])
+def test_schedule_scopes_in_op_name_metadata(schedule_hlo, scope):
+    assert re.search(rf'op_name="([^"]*/)?{re.escape(scope)}/',
+                     schedule_hlo)
