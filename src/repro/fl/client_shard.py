@@ -4,15 +4,15 @@ The paper's scheduler consumes only instantaneous CSI, so the aggregator
 re-solves Theorem 2 for EVERY client EVERY round — at the ROADMAP's
 millions-of-users scale that (N,)-shaped channel -> solve -> select ->
 account pipeline is the hot path, and until this module it materialized all
-N clients on one device (a full ``jnp.nonzero`` for participant packing, a
-full O(N log N) sort for the uniform baseline's threshold). Here the client
+N clients on one device (a full-(N,) participant pack, a full O(N log N)
+sort for the uniform baseline's threshold). Here the client
 axis is sharded over a ``'client'`` device mesh axis in ONE ``shard_map``:
 
 * each device steps its N/D slice of the fading process, runs its slice of
   the Theorem-2 solve (the Pallas ``scheduler_solve`` blocks on TPU, the
   jnp closed form elsewhere — per shard, via the ``solver`` switch), and
   Bernoulli-samples its participants locally;
-* the global ``nonzero`` becomes a per-shard pack + cross-shard merge of
+* the global pack becomes a per-shard pack + cross-shard merge of
   the <= m_cap packed participant indices;
 * the uniform baseline's full sort becomes a per-shard ``lax.top_k`` +
   k-way merge of the (D * k) candidate scores;
@@ -152,11 +152,12 @@ def _top_m_threshold(score, m, k_static: int, axis_name):
 def _pack_participants_sharded(sel, q, m_cap: int, n_local: int, axis_name):
     """Per-shard pack + cross-shard merge of the first m_cap participants.
 
-    The sequential engine packs with a full-(N,) ``jnp.nonzero``; here each
-    shard packs its own selections (ascending local order) and the merge
-    concatenates shards in mesh order — ascending GLOBAL order, so the
-    packed indices match the sequential ones exactly. Only the (D, m_cap)
-    packed indices/q values and the (D,) counts cross devices.
+    The sequential engine packs with ``pack_participants``' rank search
+    over the full-(N,) prefix count; here each shard packs its own
+    selections (ascending local order) and the merge concatenates shards
+    in mesh order — ascending GLOBAL order, so the packed indices match
+    the sequential ones exactly. Only the (D, m_cap) packed indices/q
+    values and the (D,) counts cross devices.
     """
     count = jnp.sum(sel).astype(jnp.int32)
     lidx = jnp.nonzero(sel, size=m_cap, fill_value=0)[0]

@@ -255,7 +255,7 @@ def make_round_core(ds: FederatedDataset, sim: SimConfig,
             sel, q, p, t_comm, power, n_sel, pol_state = decision(
                 policy_step, acct, k_sel, gains, pol_state)
         with jax.named_scope("fl.update"):
-            # pick up to m_cap participants (nonzero packs left)
+            # pick up to m_cap participants, packed left in ascending order
             sel_idx, sel_valid, _ = pack_participants(sel, m_cap)
             q_sel = q[sel_idx]
             imgs, labs = sample_batches(k_bat, ds.client_images,

@@ -124,10 +124,23 @@ def pack_participants(sel, m_cap: int):
     selected clients that did not fit. The single-device home of the
     packing the client-sharded engine reproduces with a per-shard pack +
     cross-shard merge (``fl/client_shard.py::_pack_participants_sharded``).
+
+    Slot ``k`` holds the first lane whose prefix count of ``sel`` reaches
+    ``k + 1``: a binary search of the (non-decreasing) count, so the ids
+    are ``jnp.nonzero(sel, size=m_cap, fill_value=0)``'s, bit for bit,
+    found by ``ceil(log2(N + 1))`` gathers of ``m_cap`` lanes (unrolled:
+    no loop back-edge between them). ``nonzero`` builds them with a
+    scatter-add of all N lanes into ``m_cap`` bins, which a TPU v5e
+    serialises on the colliding bins: 8.75 ms a round at N = 10^6
+    against 0.15 ms for the search.
     """
-    n_sel = jnp.sum(sel)
-    sel_idx = jnp.nonzero(sel, size=m_cap, fill_value=0)[0]
+    count = jnp.cumsum(sel, dtype=jnp.int32)
+    n_sel = count[-1]
     sel_valid = jnp.arange(m_cap) < n_sel
+    rank = jnp.arange(1, m_cap + 1, dtype=jnp.int32)
+    sel_idx = jnp.searchsorted(count, rank, side="left",
+                               method="scan_unrolled")
+    sel_idx = jnp.where(sel_valid, sel_idx, 0)
     overflow = (n_sel - jnp.minimum(n_sel, m_cap)).astype(jnp.int32)
     return sel_idx, sel_valid, overflow
 

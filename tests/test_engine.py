@@ -260,14 +260,56 @@ def test_run_sweep_registry_policies_and_channels(small_setup):
     assert sw["n_selected"][5].mean() >= round(m) - 1.0
 
 
-def test_pack_participants_overflow():
-    """The round's pack keeps the first ``m_cap`` selected clients and
-    counts the rest."""
-    sel = np.zeros(20, bool)
-    sel[[2, 3, 7, 11, 19]] = True
-    idx, valid, overflow = pack_participants(sel, 3)
-    np.testing.assert_array_equal(idx, [2, 3, 7])
-    assert valid.all() and int(overflow) == 2 and overflow.dtype == np.int32
-    idx, valid, overflow = pack_participants(sel, 8)
-    np.testing.assert_array_equal(idx, [2, 3, 7, 11, 19, 0, 0, 0])
-    assert valid.sum() == 5 and int(overflow) == 0
+def _mask(n, lanes):
+    sel = np.zeros(n, bool)
+    sel[list(lanes)] = True
+    return sel
+
+
+def _fleet_mask(seed):
+    """N = 10^5 at the fleet cell's density (about 870 of 10^6)."""
+    return np.random.default_rng(seed).random(100_000) < 8.7e-4
+
+
+_PACK_CASES = {
+    "overflow": (lambda: _mask(20, [2, 3, 7, 11, 19]), 3),
+    "fits": (lambda: _mask(20, [2, 3, 7, 11, 19]), 8),
+    "none": (lambda: _mask(20, []), 8),
+    "all": (lambda: np.ones(20, bool), 8),
+    "exactly_m_cap": (lambda: _mask(20, [1, 4, 9, 16]), 4),
+    "m_cap_plus_1": (lambda: _mask(20, [1, 4, 9, 16, 17]), 4),
+    "lane_0": (lambda: _mask(20, [0]), 4),
+    "last_lane": (lambda: _mask(20, [19]), 4),
+    "fleet_seed_0": (lambda: _fleet_mask(0), 1024),
+    "fleet_seed_1": (lambda: _fleet_mask(1), 1024),
+    "fleet_seed_2": (lambda: _fleet_mask(2), 1024),
+}
+
+
+@pytest.mark.parametrize("case", list(_PACK_CASES))
+def test_pack_participants_overflow(case):
+    """The round's pack keeps the first ``m_cap`` selected clients in
+    ascending order, zero-fills the rest, and counts those that did not
+    fit: the same integers as ``np.flatnonzero`` and as the sized
+    ``jnp.nonzero`` it replaced."""
+    make, m_cap = _PACK_CASES[case]
+    sel = make()
+    idx, valid, overflow = pack_participants(sel, m_cap)
+    want = np.flatnonzero(sel)[:m_cap]
+    n_sel = int(sel.sum())
+    assert idx.dtype == np.int32 and overflow.dtype == np.int32
+    np.testing.assert_array_equal(idx[:len(want)], want)
+    np.testing.assert_array_equal(idx[len(want):], 0)
+    np.testing.assert_array_equal(valid, np.arange(m_cap) < n_sel)
+    assert int(overflow) == max(n_sel - m_cap, 0)
+    np.testing.assert_array_equal(
+        idx, jnp.nonzero(sel, size=m_cap, fill_value=0)[0])
+
+
+def test_pack_participants_has_no_scatter():
+    """At the fleet width the pack lowers to gathers alone: no scatter-add
+    of every lane (``jnp.nonzero``'s ``bincount``)."""
+    sel = jax.ShapeDtypeStruct((1_000_000,), jnp.bool_)
+    text = jax.jit(pack_participants, static_argnums=1).lower(
+        sel, 1024).as_text()
+    assert "gather" in text and "scatter" not in text
