@@ -178,17 +178,17 @@ def bench_engine(prof):
       XLA fuses the elementwise channel -> solve -> select -> account chain
       and the per-call dispatch/sync disappears. This is where the big
       factor lives.
-    * jnp-vs-Pallas solve at N in {128, 3597, 100k} (interpret off-TPU).
+    * the jnp Theorem-2 solve at N in {128, 3597, 100k}.
     """
     import jax
     import jax.numpy as jnp
     from repro.core import (ChannelConfig, SchedulerConfig, channel_rate,
                             draw_gains, heterogeneous_sigmas, init_state,
-                            schedule_step)
+                            schedule_step, solve_round)
     from repro.data.synthetic import make_cifar10_like
     from repro.fl.engine import (SimConfig, eval_rounds, init_carry,
                                  make_chunk_runner, make_sim_round,
-                                 make_solve_fn, make_sweep_runner)
+                                 make_sweep_runner)
     from repro.fl.simulation import time_to_accuracy
     from repro.models.registry import make_model
 
@@ -321,27 +321,23 @@ def bench_engine(prof):
               f"rounds_per_sec={rps_scan:.1f};"
               f"speedup_vs_loop={rps_scan / rps_loop:.2f}")
 
-    # --- Theorem-2 solve: jnp closed form vs Pallas kernel ---------------
+    # --- Theorem-2 solve: the jnp closed form -----------------------------
     for n in (128, 3597, 100_000):
         ch = ChannelConfig(n_clients=n)
         scfg = SchedulerConfig(n_clients=n, model_bits=32 * 555178.0)
         gains = jnp.exp(jax.random.normal(jax.random.PRNGKey(0), (n,)))
         z = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (n,)))
-        for solver in ("jnp", "pallas"):
-            # Pallas runs compiled on TPU; in interpret mode elsewhere the
-            # timing documents the (expected, large) CPU validation penalty.
-            solve = jax.jit(make_solve_fn(scfg, ch, solver))
+        solve = jax.jit(lambda g, zz, scfg=scfg, ch=ch:
+                        solve_round(g, zz, scfg, ch))
+        jax.block_until_ready(solve(gains, z))
+        iters = 20
+        t0 = time.time()
+        for _ in range(iters):
             jax.block_until_ready(solve(gains, z))
-            iters = 20 if solver == "jnp" else 3
-            t0 = time.time()
-            for _ in range(iters):
-                jax.block_until_ready(solve(gains, z))
-            us = (time.time() - t0) / iters * 1e6
-            mode = ("compiled" if solver == "jnp"
-                    or jax.default_backend() == "tpu" else "interpret")
-            results[f"solve_n{n}_{solver}"] = us
-            _emit(f"engine_solve_n{n}_{solver}", us,
-                  f"per_client_ns={us * 1000 / n:.1f};mode={mode}")
+        us = (time.time() - t0) / iters * 1e6
+        results[f"solve_n{n}_jnp"] = us
+        _emit(f"engine_solve_n{n}_jnp", us,
+              f"per_client_ns={us * 1000 / n:.1f};mode=compiled")
     _dump("engine", results)
     return results
 
@@ -609,9 +605,8 @@ def bench_massive(prof):
     import jax
     import jax.numpy as jnp
     from repro.core import (ChannelConfig, SchedulerConfig,
-                            heterogeneous_sigmas)
+                            heterogeneous_sigmas, solve_round)
     from repro.fl.client_shard import make_schedule_runner
-    from repro.fl.engine import make_solve_fn
 
     n_dev = len(jax.devices())
     rounds = max(4, min(12, prof.rounds // 2))
@@ -645,7 +640,8 @@ def bench_massive(prof):
         # solve-only: the Theorem-2 closed form alone at this N
         gains = jnp.exp(jax.random.normal(jax.random.PRNGKey(1), (n,)))
         z = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (n,))) * 10
-        solve = jax.jit(make_solve_fn(scfg, ch, "jnp"))
+        solve = jax.jit(lambda g, zz, scfg=scfg, ch=ch:
+                        solve_round(g, zz, scfg, ch))
         jax.block_until_ready(solve(gains, z))
         iters = 10
         t0 = time.time()
@@ -988,7 +984,7 @@ def bench_kernels(prof):
             jax.block_until_ready(f(gains, z))
         us = (time.time() - t0) / iters * 1e6
         results["solve"][n] = us
-        _emit(f"kernel_scheduler_solve_n{n}", us,
+        _emit(f"kernel_solve_round_n{n}", us,
               f"per_client_ns={us * 1000 / n:.1f}")
 
     mode = "compiled" if jax.default_backend() == "tpu" else "interpret"

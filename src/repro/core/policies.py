@@ -118,16 +118,13 @@ def _aux0_ones(n: int) -> jax.Array:
 
 
 def _make_proposed(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
-                   solve_fn, coeffs=None) -> PolicyStep:
+                   coeffs=None) -> PolicyStep:
     """Algorithm 2. ``coeffs`` (a SolveCoeffs pytree, typically of traced
     scalars passed through the caller's jit boundary) switches the solve
     and the Eq. 9 queue update onto coefficient operands — the engines and
     the scheduler service both use this form so their decisions agree bit
-    for bit (the operand contract, repro/core/scheduler.py). ``solve_fn``
-    still wins when given (the Pallas kernel path)."""
-    if solve_fn is not None:
-        solve = solve_fn
-    elif coeffs is not None:
+    for bit (the operand contract, repro/core/scheduler.py)."""
+    if coeffs is not None:
         solve = lambda gains, z: solve_round_coeffs(gains, z, coeffs)  # noqa: E731
     else:
         solve = lambda gains, z: solve_round(gains, z, scfg, ch)  # noqa: E731
@@ -144,7 +141,7 @@ def _make_proposed(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
     return step
 
 
-def _make_uniform(scfg, ch, m_avg, solve_fn) -> PolicyStep:
+def _make_uniform(scfg, ch, m_avg) -> PolicyStep:
     from repro.core.scheduler import uniform_selection
 
     def step(key, gains, st: PolicyState, active=None, n_active=None):
@@ -175,7 +172,7 @@ def _make_uniform(scfg, ch, m_avg, solve_fn) -> PolicyStep:
     return step
 
 
-def _make_greedy(scfg, ch, m_avg, solve_fn) -> PolicyStep:
+def _make_greedy(scfg, ch, m_avg) -> PolicyStep:
     m = max(1, int(round(m_avg)))
 
     def step(key, gains, st: PolicyState, active=None, n_active=None):
@@ -194,7 +191,7 @@ def _make_greedy(scfg, ch, m_avg, solve_fn) -> PolicyStep:
     return step
 
 
-def _make_proportional(scfg, ch, m_avg, solve_fn,
+def _make_proportional(scfg, ch, m_avg,
                        q_floor: float = 1e-3) -> PolicyStep:
     def step(key, gains, st: PolicyState, active=None, n_active=None):
         if active is None:
@@ -212,7 +209,7 @@ def _make_proportional(scfg, ch, m_avg, solve_fn,
     return step
 
 
-def _make_update_aware(scfg, ch, m_avg, solve_fn,
+def _make_update_aware(scfg, ch, m_avg,
                        q_floor: float = 1e-3) -> PolicyStep:
     n = scfg.n_clients
 
@@ -237,7 +234,7 @@ def _make_update_aware(scfg, ch, m_avg, solve_fn,
     return step
 
 
-def _make_aoi_capped(scfg, ch, m_avg, solve_fn,
+def _make_aoi_capped(scfg, ch, m_avg,
                      max_age: Optional[int] = None) -> PolicyStep:
     n = scfg.n_clients
     m = max(1, int(round(m_avg)))
@@ -356,16 +353,14 @@ def _lookup(name: str):
 
 
 def make_policy(name: str, scfg: SchedulerConfig, ch: ChannelConfig, *,
-                m_avg: float = 0.0, solve_fn=None, coeffs=None,
-                **params) -> PolicyStep:
+                m_avg: float = 0.0, coeffs=None, **params) -> PolicyStep:
     """Bind a registered policy to its configuration.
 
     ``m_avg`` is the matched average participation level M (Section VI);
-    required (> 0) by every baseline, ignored by ``proposed``. ``solve_fn``
-    optionally overrides the Theorem-2 solve (e.g. the Pallas kernel) for
-    ``proposed``; ``coeffs`` (a SolveCoeffs of runtime operands) switches
-    ``proposed`` onto the coefficient-driven solve the engines and the
-    scheduler service share — the baselines are exact-selection policies
+    required (> 0) by every baseline, ignored by ``proposed``. ``coeffs``
+    (a SolveCoeffs of runtime operands) switches ``proposed`` onto the
+    coefficient-driven solve the engines and the scheduler service
+    share — the baselines are exact-selection policies
     (comparisons, sorts, fills, one division) whose constants are
     bit-stable either way, so they ignore it. Extra ``params`` are
     policy-specific (``q_floor``, ``max_age``).
@@ -376,7 +371,7 @@ def make_policy(name: str, scfg: SchedulerConfig, ch: ChannelConfig, *,
                          f"participation), got {m_avg!r}")
     if name == "proposed" and coeffs is not None:
         params = dict(params, coeffs=coeffs)
-    return _fence(builder(scfg, ch, m_avg, solve_fn, **params))
+    return _fence(builder(scfg, ch, m_avg, **params))
 
 
 def _fence(step: PolicyStep) -> PolicyStep:

@@ -227,8 +227,7 @@ def solve_round(gains: jax.Array, z: jax.Array, cfg: SchedulerConfig,
                 ch: ChannelConfig) -> Tuple[jax.Array, jax.Array]:
     """Vectorized Theorem-2 solve: gains, z of shape (N,) -> (q, P) each (N,).
 
-    Pure jnp (this is also the oracle for the Pallas `scheduler_solve`
-    kernel). Internally the configs are folded to a :class:`SolveCoeffs`
+    Pure jnp. Internally the configs are folded to a :class:`SolveCoeffs`
     constant bundle, so this is literally :func:`solve_round_coeffs` with
     baked coefficients — the service's bitwise contract rests on that.
     """

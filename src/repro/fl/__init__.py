@@ -3,8 +3,7 @@
 # are not part of the package surface — the carry/chunk layout is free to
 # change without breaking the public API.
 from repro.fl.client_shard import make_schedule_runner
-from repro.fl.engine import (SimConfig, make_solve_fn, run_simulation_scan,
-                             run_sweep)
+from repro.fl.engine import SimConfig, run_simulation_scan, run_sweep
 from repro.fl.grid import GridSpec, run_grid
 from repro.fl.population import PopulationConfig
 from repro.fl.round import (delta_aggregate, fl_round, local_sgd,
@@ -17,7 +16,7 @@ from repro.fl.tournament import run_tournament
 __all__ = ["fl_round", "local_sgd", "make_fl_train_step", "make_train_step",
            "weighted_aggregate", "delta_aggregate",
            "make_sharded_round_update", "make_schedule_runner",
-           "SimConfig", "make_solve_fn",
+           "SimConfig",
            "GridSpec", "run_grid",
            "PopulationConfig", "run_tournament",
            "run_simulation", "run_simulation_loop", "run_simulation_scan",

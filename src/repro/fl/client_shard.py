@@ -9,8 +9,8 @@ sort for the uniform baseline's threshold). Here the client
 axis is sharded over a ``'client'`` device mesh axis in ONE ``shard_map``:
 
 * each device steps its N/D slice of the fading process, runs its slice of
-  the Theorem-2 solve (the Pallas ``scheduler_solve`` blocks on TPU, the
-  jnp closed form elsewhere — per shard, via the ``solver`` switch), and
+  the Theorem-2 decision (the jnp closed form, or under
+  ``solver="pallas_fused"`` the fused decision kernel, per shard), and
   Bernoulli-samples its participants locally;
 * the global pack becomes a per-shard pack + cross-shard merge of
   the <= m_cap packed participant indices;
@@ -66,7 +66,7 @@ from repro.core.scheduler import (coeff_rate, greedy_coeffs,
                                   solve_round_coeffs, uniform_coeffs,
                                   uniform_draw_m, update_queues_z)
 from repro.fl.decision import (DecisionCoeffs, channel_obs, decision_coeffs,
-                               decision_step)
+                               decision_step, uses_fused)
 from repro.fl.round import pack_participants
 from repro.fl.sharding import (ACCOUNT_BLOCKS, blocked_total_sharded,
                                pad_client_axis, padded_len, shard_map)
@@ -180,15 +180,12 @@ def _pack_participants_sharded(sel, q, m_cap: int, n_local: int, axis_name):
 # --------------------------------------------------------------------------
 
 def _sharded_proposed(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
-                      solve_fn, n_real: int, n_local: int, axis_name: str):
+                      n_real: int, n_local: int, axis_name: str):
     def step(raw, gains, z, aux, t, valid, local_ids, co, active=None,
              n_act=None):
-        # solve_fn wins when given (the Pallas kernel); otherwise the
-        # coefficient-driven solve on the runtime bundle — the operand
+        # the coefficient-driven solve on the runtime bundle — the operand
         # contract the sequential engine shares (repro/core/scheduler.py)
-        solve = solve_fn or (
-            lambda g, zz: solve_round_coeffs(g, zz, co.solve))
-        q, p = solve(gains, z)
+        q, p = solve_round_coeffs(gains, z, co.solve)
         if active is not None:
             # the sequential masked step's q -> 0 on inactive lanes, BEFORE
             # selection and the Eq. 9 charge (repro.core.policies)
@@ -207,8 +204,7 @@ def _sharded_proposed(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
 
 
 def _sharded_proposed_fused(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
-                            solve_fn, n_real: int, n_local: int,
-                            axis_name: str):
+                            n_real: int, n_local: int, axis_name: str):
     """The megakernel twin of :func:`_sharded_proposed`: each shard runs
     solve + Bernoulli comparison + Eq. 9 queue update as ONE Pallas pass
     over its (n_local,) slice (``kernels/decision_fused.py``), bitwise-
@@ -240,7 +236,7 @@ def _sharded_proposed_fused(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
 
 
 def _sharded_uniform(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
-                     solve_fn, n_real: int, n_local: int, axis_name: str):
+                     n_real: int, n_local: int, axis_name: str):
     m_hi = int(np.floor(m_avg)) + 1  # static bound: m' in [1, min(m_hi, N)]
     k_static = max(1, min(n_local, min(m_hi, n_real)))
     # the same host-folded f32 coefficients the sequential uniform_decide
@@ -275,7 +271,7 @@ def _sharded_uniform(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
 
 
 def _sharded_greedy(scfg: SchedulerConfig, ch: ChannelConfig, m_avg,
-                    solve_fn, n_real: int, n_local: int, axis_name: str):
+                    n_real: int, n_local: int, axis_name: str):
     c = greedy_coeffs(n_real, m_avg, ch)
     m = int(c.m)
     k_static = max(1, min(n_local, min(m, n_real)))
@@ -344,8 +340,8 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
                           channel_params: tuple, scfg: SchedulerConfig,
                           ch: ChannelConfig, sigmas: jax.Array, *,
                           n_shards: int, m_cap: int, m_avg: float = 0.0,
-                          solve_fn=None, population=None, devices=None,
-                          fused: bool = False, mesh=None):
+                          population=None, devices=None, fused: bool = False,
+                          mesh=None):
     """Build the one-``shard_map`` scheduling step for one round.
 
     Returns ``schedule(raw_ch, raw_pol, pol_state, ch_state, co) ->
@@ -369,7 +365,8 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
     stragglers (selected-but-failed) keep their airtime and count but are
     dropped from the packed participants.
 
-    ``fused=True`` (``solver="pallas_fused"``, ``policy="proposed"`` only)
+    ``fused=True`` (``policy="proposed"`` only; callers pass
+    ``repro.fl.decision.uses_fused``)
     swaps the per-shard policy step for the fused Pallas megakernel
     variant — solve + selection + Eq. 9 in one pass per shard slice,
     bitwise-equal to the stitched step (tests/test_decision_fused.py).
@@ -410,7 +407,7 @@ def make_sharded_schedule(sim_policy: str, sim_channel: str,
                          "policy with a fused decision kernel)")
     make_step = (_sharded_proposed_fused if fused
                  else _SHARDED_POLICIES[sim_policy])
-    policy_step = make_step(scfg, ch, m_avg, solve_fn, n, n_local, "client")
+    policy_step = make_step(scfg, ch, m_avg, n, n_local, "client")
     sig_pad = pad_client_axis(sigmas, n_pad, 0.0)
 
     def account_and_pack(gains, valid, sel, q, p, delivered, co):
@@ -651,23 +648,20 @@ class ScheduleChunks:
 def _schedule_rounds(sigmas: jax.Array, scfg: SchedulerConfig,
                      ch: ChannelConfig, *, policy: str, m_avg: float,
                      channel: str, channel_params: tuple, solver: str,
-                     client_shards: int, m_cap: int, solve_fn, devices):
+                     client_shards: int, m_cap: int, devices):
     """``scan_rounds(carry, co, n_rounds) -> (carry, (t_comm, power, n_sel,
     overflow, ids))``, each stacked over the rounds: the one scheduling
     round body both runners scan (see :func:`make_schedule_chunk_runner`).
     The ids and the overflow are separate outputs, so a caller that drops
     them drops the pack with them."""
-    from repro.fl.engine import resolve_solve_fn
-
     n = int(sigmas.shape[0])
-    solve = resolve_solve_fn(scfg, ch, solver, solve_fn)
-    fused = solver == "pallas_fused" and policy == "proposed"
+    fused = uses_fused(solver, policy)
     chan = make_channel(channel, sigmas, ch, **dict(channel_params))
     if client_shards:
         schedule = make_sharded_schedule(
             policy, channel, channel_params, scfg, ch, sigmas,
             n_shards=client_shards, m_cap=m_cap, m_avg=m_avg,
-            solve_fn=solve, devices=devices, fused=fused)
+            devices=devices, fused=fused)
 
         def round_fn(pol_state, ch_state, k, co):
             k_ch, k_sel, _ = jax.random.split(k, 3)
@@ -686,7 +680,7 @@ def _schedule_rounds(sigmas: jax.Array, scfg: SchedulerConfig,
             # the sequential reference IS the shared decision layer (the
             # same function the scan engine and the service run)
             step = make_policy(policy, scfg, ch, m_avg=m_avg,
-                               solve_fn=solve, coeffs=co.solve)
+                               coeffs=co.solve)
             decision = decision_step
             if fused:
                 from repro.fl.decision import make_fused_decision
@@ -718,7 +712,7 @@ def make_schedule_chunk_runner(sigmas: jax.Array, scfg: SchedulerConfig,
                                channel: str = "rayleigh",
                                channel_params: tuple = (),
                                solver: str = "jnp", client_shards: int = 0,
-                               m_cap: int = 32, solve_fn=None,
+                               m_cap: int = 32,
                                devices=None) -> ScheduleChunks:
     """The scheduling layer alone (no model training, no dataset), stepped:
     ``run_chunk(carry, n_rounds) -> (carry, rows)`` from a carry of
@@ -751,7 +745,7 @@ def make_schedule_chunk_runner(sigmas: jax.Array, scfg: SchedulerConfig,
         _schedule_rounds(sigmas, scfg, ch, policy=policy, m_avg=m_avg,
                          channel=channel, channel_params=channel_params,
                          solver=solver, client_shards=client_shards,
-                         m_cap=m_cap, solve_fn=solve_fn, devices=devices),
+                         m_cap=m_cap, devices=devices),
         decision_coeffs(scfg, ch))
 
 
@@ -761,7 +755,7 @@ def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
                          channel: str = "rayleigh",
                          channel_params: tuple = (), solver: str = "jnp",
                          client_shards: int = 0, m_cap: int = 32,
-                         solve_fn=None, devices=None):
+                         devices=None):
     """A whole scheduling-layer trajectory from fresh queues.
 
     ``runner(key) -> (t_comm, power, n_sel)``, each (rounds,):
@@ -776,8 +770,7 @@ def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
     scan_rounds = _schedule_rounds(
         sigmas, scfg, ch, policy=policy, m_avg=m_avg, channel=channel,
         channel_params=channel_params, solver=solver,
-        client_shards=client_shards, m_cap=m_cap, solve_fn=solve_fn,
-        devices=devices)
+        client_shards=client_shards, m_cap=m_cap, devices=devices)
     chan = make_channel(channel, sigmas, ch, **dict(channel_params))
     n = int(sigmas.shape[0])
     co = jax.device_put(decision_coeffs(scfg, ch))
@@ -800,7 +793,6 @@ def make_schedule_runner(sigmas: jax.Array, scfg: SchedulerConfig,
 
 def make_client_sharded_round(ds, sim, scfg: SchedulerConfig,
                               ch: ChannelConfig, sigmas: jax.Array,
-                              solve_fn=None,
                               coeffs: DecisionCoeffs = None):
     """The client-sharded ``sim_round`` for the scan engine.
 
@@ -823,7 +815,7 @@ def make_client_sharded_round(ds, sim, scfg: SchedulerConfig,
     over: mesh (1, 1) stays bitwise-equal to ``run_simulation_scan`` and
     integer accounting stays exact on every mesh (tests/test_mesh2d.py).
     """
-    from repro.fl.engine import resolve_solve_fn, resolve_wire_dtype
+    from repro.fl.engine import resolve_wire_dtype
     from repro.fl.round import (local_sgd, make_sharded_round_update,
                                 masked_aggregate, sample_batches)
     from repro.fl.sharding import make_mesh2d
@@ -832,7 +824,6 @@ def make_client_sharded_round(ds, sim, scfg: SchedulerConfig,
     n = ds.n_clients
     spec = make_model(sim.model, ds, **dict(sim.model_params))
     wire = resolve_wire_dtype(sim.wire_dtype)
-    solve = resolve_solve_fn(scfg, ch, sim.solver, solve_fn)
     co = coeffs if coeffs is not None else decision_coeffs(scfg, ch)
     mesh2d = None
     sharded_update = None
@@ -845,8 +836,8 @@ def make_client_sharded_round(ds, sim, scfg: SchedulerConfig,
     schedule = make_sharded_schedule(
         sim.policy, sim.channel, sim.channel_params, scfg, ch, sigmas,
         n_shards=sim.client_shards, m_cap=sim.m_cap, m_avg=sim.uniform_m,
-        solve_fn=solve, population=sim.population, mesh=mesh2d,
-        fused=(sim.solver == "pallas_fused" and sim.policy == "proposed"))
+        population=sim.population, mesh=mesh2d,
+        fused=uses_fused(sim.solver, sim.policy))
 
     def sim_round(params, pol_state, ch_state, key):
         k_ch, k_sel, k_bat = jax.random.split(key, 3)

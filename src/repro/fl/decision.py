@@ -11,7 +11,7 @@ the binding correctness contract is that a served decision is
 bitwise-equal to the decision the simulation engine would have taken
 (tests/test_service.py).
 
-Three pieces:
+Four pieces:
 
 * :class:`DecisionCoeffs` — the decision layer's scalar operands (the
   Theorem-2 :class:`~repro.core.scheduler.SolveCoeffs` plus the
@@ -30,6 +30,10 @@ Three pieces:
   (Theorem-2 solve + Bernoulli selection + Eq. 9 queue update for
   ``proposed``), TDMA comm-time and average-power accounting through the
   mesh-invariant blocked reduction.
+* :func:`uses_fused` — the one place that picks between the two
+  implementations of that decision: the stitched jnp ``decision_step``
+  (every policy) and the fused Pallas kernel (``proposed`` only,
+  :func:`make_fused_decision` and its sharded and batched twins).
 """
 
 from __future__ import annotations
@@ -44,6 +48,24 @@ from repro.core.channel import ChannelConfig
 from repro.core.scheduler import (SchedulerConfig, SolveCoeffs, coeff_rate,
                                   solve_coeffs)
 from repro.fl.sharding import blocked_total
+
+
+# The ``solver`` names every engine, runner and the service accept.
+SOLVERS = ("jnp", "pallas_fused")
+
+
+def uses_fused(solver: str, policy: str) -> bool:
+    """True iff ``(solver, policy)`` runs the fused decision kernel.
+
+    ``"pallas_fused"`` serves ``proposed`` through
+    ``kernels/decision_fused.py``; every other policy, and every policy
+    under ``"jnp"``, runs the stitched :func:`decision_step`, which the
+    fused path is bitwise-equal to. Any other solver name raises.
+    """
+    if solver not in SOLVERS:
+        raise ValueError(f"unknown solver {solver!r} (want one of "
+                         f"{', '.join(repr(s) for s in SOLVERS)})")
+    return solver == "pallas_fused" and policy == "proposed"
 
 
 class AccountCoeffs(NamedTuple):

@@ -20,10 +20,11 @@ registries (``repro.core.channel``, ``repro.core.policies``):
   re-tracing per configuration, and without a mixed-policy body that pays
   for branches it discards (each per-policy runner is pruned to exactly
   that policy's ops).
-* ``make_solve_fn`` is the Theorem-2 solve behind a ``solver`` switch:
-  ``"jnp"`` is the vectorized closed form from ``repro.core.scheduler``;
-  ``"pallas"`` is the tiled VPU kernel from ``repro.kernels``, with
-  ``interpret`` auto-selected off-TPU so the same config runs everywhere.
+* ``SimConfig.solver`` picks how the Theorem-2 decision runs, through
+  ``repro.fl.decision.uses_fused``: ``"jnp"`` is the stitched closed form
+  from ``repro.core.scheduler``; ``"pallas_fused"`` runs ``proposed``
+  through the fused kernel ``kernels/decision_fused.py``, compiled on TPU
+  and in interpret mode elsewhere, so the same config runs everywhere.
 * ``SimConfig.model`` picks WHAT federates through the model registry
   (``repro.models.registry``: cnn | mlp | transformer_lm), and
   ``SimConfig.participant_shards`` picks HOW: 0 trains the sampled
@@ -67,7 +68,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +80,7 @@ from repro.core import (ChannelConfig, SchedulerConfig, channel_rate,
 from repro.core.policies import POLICY_IDS  # noqa: F401  (re-exported)
 from repro.data.synthetic import FederatedDataset
 from repro.fl.decision import (DecisionCoeffs, channel_obs, decision_coeffs,
-                               decision_step)
+                               decision_step, uses_fused)
 from repro.fl.round import (local_sgd, make_sharded_round_update,
                             masked_aggregate, pack_participants,
                             sample_batches)
@@ -109,9 +110,9 @@ class SimConfig:
     uniform_m: float = 0.0       # matched M for the baseline policies
     seed: int = 0
     engine: str = "scan"         # scan (compiled chunks) | loop (legacy)
-    solver: str = "jnp"          # jnp closed form | pallas solve kernel |
-                                 # pallas_fused (the full-decision megakernel
-                                 # for policy="proposed"; other policies fall
+    solver: str = "jnp"          # jnp closed form | pallas_fused (the
+                                 # full-decision megakernel for
+                                 # policy="proposed"; other policies fall
                                  # back to the stitched jnp path, which the
                                  # fused path is bitwise-equal to)
     channel: str = "rayleigh"    # any repro.core.channel.CHANNEL_MODELS name
@@ -139,41 +140,6 @@ class SimConfig:
                                  # activity mask; () is the degenerate
                                  # all-active scenario, bitwise-equal to
                                  # None on mesh 1 (tests/test_population.py)
-
-
-# --------------------------------------------------------------------------
-# Theorem-2 solve dispatch: jnp closed form vs Pallas kernel.
-# --------------------------------------------------------------------------
-
-def make_solve_fn(scfg: SchedulerConfig, ch: ChannelConfig,
-                  solver: str = "jnp", interpret: Optional[bool] = None,
-                  block: Optional[int] = None
-                  ) -> Callable[[jax.Array, jax.Array], tuple]:
-    """Return ``solve(gains, z) -> (q, P)`` for the configured backend.
-
-    ``solver="pallas"`` runs the tiled kernel compiled on TPU and in
-    interpret mode elsewhere (override with ``interpret``). The returned
-    closure accepts any 1-D client slice, so the client-sharded engine can
-    call it per shard; ``block`` overrides the kernel's tile length (e.g.
-    to keep shard-local interpret-mode runs small).
-    """
-    if solver == "jnp":
-        from repro.core import solve_round
-        return lambda gains, z: solve_round(gains, z, scfg, ch)
-    if solver != "pallas":
-        raise ValueError(f"unknown solver {solver!r} (want 'jnp'|'pallas')")
-    from repro.kernels.scheduler_solve import scheduler_solve
-
-    def solve(gains, z):
-        # interpret=None lets scheduler_solve auto-select (compiled on TPU)
-        kw = {} if block is None else {"block": block}
-        return scheduler_solve(
-            gains, z, n=scfg.n_clients, v=scfg.V, lam=scfg.lam,
-            ell=scfg.model_bits, bandwidth=ch.bandwidth_hz,
-            noise=ch.noise_power, p_max=ch.p_max, p_bar=ch.p_bar,
-            q_floor=scfg.q_floor, interpret=interpret, **kw)
-
-    return solve
 
 
 # --------------------------------------------------------------------------
@@ -278,25 +244,6 @@ def make_round_core(ds: FederatedDataset, sim: SimConfig,
     return round_core
 
 
-def resolve_solve_fn(scfg: SchedulerConfig, ch: ChannelConfig, solver: str,
-                     solve_fn=None):
-    """The engine's solve override: an explicit ``solve_fn`` wins, the
-    Pallas kernel is built for ``solver="pallas"``, and ``None`` is
-    returned for the jnp path — which then runs the coefficient-driven
-    ``solve_round_coeffs`` on the runtime bundle (the operand contract).
-
-    ``"pallas_fused"`` also returns None: the megakernel replaces the
-    whole DECISION layer, not the solve closure, so any consumer that
-    only takes a solve function (sweeps, baseline policies, matched-M
-    estimation) runs the stitched jnp path — which the fused path is
-    bitwise-equal to, so nothing diverges."""
-    if solve_fn is not None:
-        return solve_fn
-    if solver in ("jnp", "pallas_fused"):
-        return None
-    return make_solve_fn(scfg, ch, solver)
-
-
 def resolve_fused_decision(sim: SimConfig, scfg: SchedulerConfig, co):
     """``solver="pallas_fused"`` -> the megakernel decision drop-in, else
     None (callers then keep :func:`repro.fl.decision.decision_step`).
@@ -307,7 +254,7 @@ def resolve_fused_decision(sim: SimConfig, scfg: SchedulerConfig, co):
     ``co`` may hold traced leaves (the engines call this inside jit with
     the runtime bundle — the operand contract).
     """
-    if sim.solver == "pallas_fused" and sim.policy == "proposed":
+    if uses_fused(sim.solver, sim.policy):
         from repro.fl.decision import make_fused_decision
         return make_fused_decision(scfg, co)
     return None
@@ -315,7 +262,7 @@ def resolve_fused_decision(sim: SimConfig, scfg: SchedulerConfig, co):
 
 def make_sim_round(ds: FederatedDataset, sim: SimConfig,
                    scfg: SchedulerConfig, ch: ChannelConfig,
-                   sigmas: jax.Array, solve_fn=None,
+                   sigmas: jax.Array,
                    coeffs: Optional[DecisionCoeffs] = None):
     """Bind :func:`make_round_core` to one concrete channel model + policy.
 
@@ -336,17 +283,14 @@ def make_sim_round(ds: FederatedDataset, sim: SimConfig,
     if sim.client_shards:
         from repro.fl.client_shard import make_client_sharded_round
         return make_client_sharded_round(ds, sim, scfg, ch, sigmas,
-                                         solve_fn, coeffs=co)
+                                         coeffs=co)
     if sim.population is not None:
         from repro.fl.population import make_population_round
-        return make_population_round(ds, sim, scfg, ch, sigmas, solve_fn,
-                                     coeffs=co)
-    solve = resolve_solve_fn(scfg, ch, sim.solver, solve_fn)
+        return make_population_round(ds, sim, scfg, ch, sigmas, coeffs=co)
     channel = make_channel(sim.channel, sigmas, ch,
                            **dict(sim.channel_params))
     policy_step = make_policy(sim.policy, scfg, ch, m_avg=sim.uniform_m,
-                              solve_fn=solve, coeffs=co.solve,
-                              **dict(sim.policy_params))
+                              coeffs=co.solve, **dict(sim.policy_params))
     round_core = make_round_core(ds, sim, scfg,
                                  decision=resolve_fused_decision(sim, scfg,
                                                                  co))
@@ -399,7 +343,7 @@ def scan_chunk(sim_round, eval_fn, carry, n_rounds: int):
 
 def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
                       scfg: SchedulerConfig, ch: ChannelConfig,
-                      sigmas: jax.Array, solve_fn=None):
+                      sigmas: jax.Array):
     """Build the jitted multi-round chunk function behind the scan engine.
 
     ``run_chunk(carry, n_rounds)`` scans ``sim_round`` ``n_rounds`` times
@@ -434,8 +378,7 @@ def make_chunk_runner(ds: FederatedDataset, sim: SimConfig,
     @functools.partial(jax.jit, static_argnames=("n_rounds",),
                        donate_argnums=(0,))
     def _run_chunk(carry, co, data, n_rounds):
-        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, solve_fn,
-                                   coeffs=co)
+        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, coeffs=co)
         return scan_chunk(sim_round, make_eval_fn(data, sim), carry,
                           n_rounds)
 
@@ -533,7 +476,7 @@ def run_config_chunks(sim_round, eval_fn, rounds: int, eval_every: int,
 
 def make_config_runner(ds: FederatedDataset, sim: SimConfig,
                        scfg: SchedulerConfig, ch: ChannelConfig,
-                       sigmas: jax.Array, solve_fn=None):
+                       sigmas: jax.Array):
     """Jit the full single-config trajectory: ``runner(params, key) ->
     (comm_cum, test_acc, power_cum, n_selected)``, each (E,).
 
@@ -547,8 +490,7 @@ def make_config_runner(ds: FederatedDataset, sim: SimConfig,
 
     @jax.jit
     def _runner(params, key, co, data):
-        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, solve_fn,
-                                   coeffs=co)
+        sim_round = make_sim_round(data, sim, scfg, ch, sigmas, coeffs=co)
         pol0 = init_policy_state(sim.policy, n)
         ch0 = init_channel_carry(key, sim, channel, n)
         return run_config_chunks(sim_round, make_eval_fn(data, sim),
@@ -616,7 +558,7 @@ def make_sweep_runner(sigmas: jax.Array, scfg: SchedulerConfig,
                       ch: ChannelConfig, *, rounds: int,
                       policy: str = "proposed", m_avg: float = 1.0,
                       channel: str = "rayleigh", channel_params: tuple = (),
-                      solver: str = "jnp", guarantee_one: bool = True,
+                      guarantee_one: bool = True,
                       policy_params: Optional[dict] = None):
     """Build the jitted batched scheduling-trajectory function for ONE policy.
 
@@ -632,7 +574,6 @@ def make_sweep_runner(sigmas: jax.Array, scfg: SchedulerConfig,
     """
     n = scfg.n_clients
     scfg_run = dataclasses.replace(scfg, guarantee_one=guarantee_one)
-    solve = resolve_solve_fn(scfg_run, ch, solver)
     chan = make_channel(channel, sigmas, ch, **dict(channel_params))
     co_host = decision_coeffs(scfg_run, ch)
 
@@ -643,8 +584,7 @@ def make_sweep_runner(sigmas: jax.Array, scfg: SchedulerConfig,
         # reduce) is deliberately kept — it is statistical output, not
         # part of any bitwise contract
         step = make_policy(policy, scfg_run, ch, m_avg=m_avg,
-                           solve_fn=solve, coeffs=co.solve,
-                           **(policy_params or {}))
+                           coeffs=co.solve, **(policy_params or {}))
 
         def body(carry, k):
             pst, cst = carry
@@ -701,6 +641,10 @@ def run_sweep(key, sigmas: jax.Array, scfg: SchedulerConfig,
     if unknown:
         raise ValueError(f"unknown policies {unknown} "
                          f"(registered: {sorted(POLICIES)})")
+    # the sweep keeps its own plain-sum accounting, so every policy runs
+    # the stitched step under either solver; the name is still checked,
+    # before the matched-M Monte Carlo
+    uses_fused(solver, "proposed")
     needs_m = any(POLICIES[p][2] for p in policies)
     if uniform_m is None:
         if needs_m:
@@ -723,7 +667,7 @@ def run_sweep(key, sigmas: jax.Array, scfg: SchedulerConfig,
     for p in policies:
         runner = make_sweep_runner(
             sigmas, scfg, ch, rounds=rounds, policy=p, m_avg=uniform_m,
-            channel=channel, channel_params=channel_params, solver=solver,
+            channel=channel, channel_params=channel_params,
             guarantee_one=guarantee_one,
             policy_params=(policy_params or {}).get(p))
         per_policy.append(runner(seed_keys))

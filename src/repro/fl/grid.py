@@ -53,10 +53,9 @@ from repro.core import ChannelConfig, SchedulerConfig, resolve_sigmas
 from repro.core.channel import CHANNEL_MODELS
 from repro.core.policies import POLICIES, init_policy_state, make_policy
 from repro.data.synthetic import FederatedDataset
-from repro.fl.decision import decision_coeffs
+from repro.fl.decision import decision_coeffs, uses_fused
 from repro.fl.engine import (CHANNEL_INIT_TAG, SimConfig, eval_rounds,
-                             make_eval_fn, make_round_core,
-                             resolve_solve_fn, run_config_chunks)
+                             make_eval_fn, make_round_core, run_config_chunks)
 from repro.fl.sharding import shard_map
 
 
@@ -185,7 +184,9 @@ def make_grid_runner(ds: FederatedDataset, sim: SimConfig,
     mesh = Mesh(np.array(devices), ("grid",))
 
     sigma_table = jnp.stack([resolve_sigmas(d, n) for d in spec.sigma_dists])
-    solve = resolve_solve_fn(scfg, ch, sim.solver)
+    # every cell runs the stitched decision, which the fused kernel is
+    # bitwise-equal to; the solver name is still checked
+    uses_fused(sim.solver, "proposed")
     round_core = make_round_core(ds, sim, scfg)
     eval_fn = make_eval_fn(ds, sim)
     co_host = decision_coeffs(scfg, ch)
@@ -213,8 +214,7 @@ def make_grid_runner(ds: FederatedDataset, sim: SimConfig,
             # the policy binds to the RUNTIME coefficient bundle (operand
             # contract, repro/fl/decision.py) — same as run_simulation_scan
             policy_step = make_policy(pname, scfg, ch, m_avg=sim.uniform_m,
-                                      solve_fn=solve, coeffs=co.solve,
-                                      **dict(pparams))
+                                      coeffs=co.solve, **dict(pparams))
             sig = sigma_table[sid]
             ch_state = init_fn(jax.random.fold_in(key, CHANNEL_INIT_TAG),
                                sig, ch, **ckw)

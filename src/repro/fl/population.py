@@ -231,20 +231,18 @@ def make_population_core(ds: FederatedDataset, sim, scfg: SchedulerConfig,
 
 
 def make_population_round(ds: FederatedDataset, sim, scfg: SchedulerConfig,
-                          ch, sigmas: jax.Array, solve_fn=None,
+                          ch, sigmas: jax.Array,
                           coeffs: DecisionCoeffs = None):
     """Bind :func:`make_population_core` to ``sim``'s channel + policy —
     the population twin of ``engine.make_sim_round``'s sequential path
     (``make_sim_round`` dispatches here when ``sim.population`` is set)."""
-    from repro.fl.engine import resolve_fused_decision, resolve_solve_fn
+    from repro.fl.engine import resolve_fused_decision
     pcfg = population_config(sim.population)
     co = coeffs if coeffs is not None else decision_coeffs(scfg, ch)
-    solve = resolve_solve_fn(scfg, ch, sim.solver, solve_fn)
     channel = make_channel(sim.channel, sigmas, ch,
                            **dict(sim.channel_params))
     policy_step = make_policy(sim.policy, scfg, ch, m_avg=sim.uniform_m,
-                              solve_fn=solve, coeffs=co.solve,
-                              **dict(sim.policy_params))
+                              coeffs=co.solve, **dict(sim.policy_params))
     pop_core = make_population_core(ds, sim, scfg, pcfg,
                                     decision=resolve_fused_decision(sim,
                                                                     scfg,

@@ -35,14 +35,14 @@ from repro.core import (ChannelConfig, SchedulerConfig, channel_rate,
                         draw_gains, estimate_avg_selected, init_state,
                         schedule_step, uniform_selection)
 from repro.data.synthetic import FederatedDataset
-from repro.fl.engine import (SimConfig, make_solve_fn, resolve_wire_dtype,
+from repro.fl.engine import (SimConfig, resolve_wire_dtype,
                              run_simulation_scan, run_sweep)
 from repro.fl.round import local_sgd
 from repro.models.registry import make_model
 
 __all__ = ["SimConfig", "run_simulation", "run_simulation_loop",
-           "run_simulation_scan", "run_sweep", "make_solve_fn",
-           "match_uniform_m", "time_to_accuracy"]
+           "run_simulation_scan", "run_sweep", "match_uniform_m",
+           "time_to_accuracy"]
 
 
 def _select_proposed(key, gains, sched_state, scfg, ch):

@@ -1,16 +1,14 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
-Three kernels, each with a BlockSpec-tiled `pl.pallas_call` implementation,
+Two kernels, each with a BlockSpec-tiled `pl.pallas_call` implementation,
 a jit'd wrapper in ops.py, and a pure-jnp oracle in ref.py:
 
-* ``scheduler_solve`` — the paper's Theorem-2 per-client closed form
-  (Lambert-W power + Eq.17 probability), tiled over the client vector.
 * ``flash_attention`` — online-softmax attention with VMEM scratch
   accumulators (used by 8 of the 10 assigned architectures).
 * ``ssd_scan`` — Mamba-2 chunked state-space-duality scan (mamba2, jamba).
 
-Plus the fused decision megakernel (``decision_fused``), which subsumes
-``scheduler_solve`` for the ``proposed`` policy: one pass computing
+Plus the fused decision megakernel (``decision_fused``), the paper's
+Theorem-2 decision for the ``proposed`` policy: one pass computing
 solve + Bernoulli selection + Eq. (9) Z-update + accounting summands,
 bitwise-equal to the stitched ``fl/decision.py::decision_step`` because
 it reuses the jnp oracle's traced helpers on runtime-operand scalars.
@@ -21,9 +19,8 @@ from repro.kernels.decision_fused import (N_DECISION_OPS, decision_fused,
                                           decision_fused_batched,
                                           pack_decision_operands)
 from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.scheduler_solve import scheduler_solve
 from repro.kernels.ssd_scan import ssd_scan
 
-__all__ = ["ops", "ref", "flash_attention_bhsd", "scheduler_solve",
-           "ssd_scan", "decision_fused", "decision_fused_batched",
+__all__ = ["ops", "ref", "flash_attention_bhsd", "ssd_scan",
+           "decision_fused", "decision_fused_batched",
            "pack_decision_operands", "N_DECISION_OPS"]

@@ -3,16 +3,15 @@ summands in one pass over the (N,) client state.
 
 The deployable per-round artifact of the paper is the full decision —
 CSI observation -> Theorem-2 power/probability solve -> Bernoulli
-selection -> Eq. (9) virtual-queue update -> TDMA accounting — but only
-the solve ran in Pallas (``kernels/scheduler_solve.py``); everything else
-was stitched XLA around ``fl/decision.py::decision_step``. This kernel
-performs the whole post-observation decision in a single tiled pass:
+selection -> Eq. (9) virtual-queue update -> TDMA accounting. Its
+stitched form is XLA around ``fl/decision.py::decision_step``; this
+kernel performs the whole post-observation decision in a single tiled
+pass:
 
 * Theorem-2 solve — the SAME traced helpers as the jnp oracle
   (:func:`repro.core.scheduler.solve_round_coeffs`, including the
   fixed-iteration Halley Lambert-W), evaluated per block. Reusing the
-  oracle's exact op sequence (rather than restating it, as the
-  solve-only kernel must for its baked-constant signature) is what makes
+  oracle's exact op sequence (rather than restating it) is what makes
   the interpreted fused path BITWISE-equal to the stitched composition.
   Compiled for the chip, Mosaic and XLA lower exp/log differently, so
   the two agree to round-off (``chip_smoke.py`` states the tolerance).
@@ -182,9 +181,9 @@ def decision_fused(gains: jax.Array, z: jax.Array, u: jax.Array,
       ``where(sel_final, tc, 0)`` and folds through ``blocked_total``;
     * ``pq`` — per-lane expected power ``P q`` (validity-masked).
 
-    Pad hygiene mirrors ``scheduler_solve``: internal padding to a block
-    multiple uses gains = 1.0 / Z = 0 (finite solve), u = 2.0 (never
-    selected), masks False, and is sliced off before returning.
+    Pad hygiene: internal padding to a block multiple uses gains = 1.0 /
+    Z = 0 (finite solve), u = 2.0 (never selected), masks False, and is
+    sliced off before returning.
     ``interpret=None`` auto-selects interpret mode off-TPU; ``block`` is
     value-invariant (tests pin bitwise equality across overrides).
     """

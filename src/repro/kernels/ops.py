@@ -13,11 +13,10 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention_bhsd
-from repro.kernels.scheduler_solve import scheduler_solve
 from repro.kernels.ssd_scan import ssd_scan
 
-__all__ = ["flash_attention", "ssd", "ssd_decode_step", "scheduler_solve",
-           "attention_auto", "ssd_auto", "on_tpu"]
+__all__ = ["flash_attention", "ssd", "ssd_decode_step", "attention_auto",
+           "ssd_auto", "on_tpu"]
 
 
 def on_tpu() -> bool:

@@ -119,15 +119,3 @@ def ssd_chunked_ref(x, dt, a, bm, cm, *, chunk: int = 128,
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p).astype(x.dtype)
     return y, h_final
 
-
-def scheduler_solve_ref(gains, z, *, n, v, lam, ell, bandwidth, noise,
-                        p_max, p_bar, q_floor=1e-5):
-    """Oracle = the paper-core vectorized Theorem-2 solve."""
-    from repro.core.channel import ChannelConfig
-    from repro.core.scheduler import SchedulerConfig, solve_round
-
-    ch = ChannelConfig(n_clients=n, bandwidth_hz=bandwidth, noise_power=noise,
-                       p_max=p_max, p_bar=p_bar)
-    cfg = SchedulerConfig(n_clients=n, model_bits=ell, lam=lam, V=v,
-                          q_floor=q_floor)
-    return solve_round(gains, z, cfg, ch)

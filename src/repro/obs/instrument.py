@@ -81,16 +81,6 @@ class CompileTracker:
         self._warmed.add(key)
         return fresh
 
-    def forget(self, prefix: Hashable) -> None:
-        """Drop every tracked signature whose key starts with ``prefix``
-        — mirrors a jit-cache drop (the service invalidating a bucket's
-        ``solver='pallas'`` step), so the next dispatch of a previously
-        seen shape correctly counts as a fresh compile."""
-        stale = {k for k in self._seen
-                 if isinstance(k, tuple) and k and k[0] == prefix}
-        self._seen -= stale
-        self._warmed -= stale
-
     def misses_total(self) -> float:
         return self.registry.total(f"{self.prefix}_compile_misses_total")
 

@@ -78,6 +78,7 @@ from repro.core.channel import ChannelConfig
 from repro.core.policies import POLICY_DRAWS, PolicyState
 from repro.core.scheduler import SchedulerConfig
 from repro.fl.client_shard import POLICY_RAW_PAD
+from repro.fl.decision import uses_fused
 from repro.obs import metrics as obs_metrics
 from repro.obs.export import EventLog, json_snapshot, prometheus_text
 from repro.obs.instrument import ServiceInstruments, perf
@@ -233,20 +234,20 @@ class SchedulerService:
     >>> svc.submit("cityA", gains, key=k)                 # one round's CSI
     >>> decision = svc.flush()["cityA"]                   # (sel, q, p) + accounting
 
-    ``solver="pallas"`` swaps the Theorem-2 solve for the tiled Pallas
-    kernel (``repro.kernels.scheduler_solve``); each bucket must then be
-    configuration-homogeneous (kernel parameters are compile-time static)
-    and the bitwise-parity contract relaxes to the kernel's float32
-    round-off. The default ``"jnp"`` path serves heterogeneous tenants
-    from one compiled program per bucket and is bitwise-equal to
+    The default ``solver="jnp"`` serves heterogeneous tenants from one
+    compiled program per bucket and is bitwise-equal to
     ``run_simulation_scan``'s decisions (tests/test_service.py).
 
     ``solver="pallas_fused"`` serves ``proposed`` buckets through the
     bucket-batched fused decision megakernel
     (``kernels/decision_fused.py``): every scalar is a runtime operand
-    row, so — unlike ``"pallas"`` — heterogeneous tenants still batch in
-    one program AND the full bitwise contract holds. Non-``proposed``
-    buckets fall back to the stitched jnp rows (identical results).
+    row, so heterogeneous tenants still batch in one program AND the full
+    bitwise contract holds. Non-``proposed`` buckets fall back to the
+    stitched jnp rows (identical results).
+
+    Either way every per-tenant quantity is a runtime operand, so one
+    bucket's step serves any tenant count: admissions, evictions and
+    reloads keep its compiled (T, batch)-shape variants.
     """
 
     def __init__(self, solver: str = "jnp", log_requests: bool = True,
@@ -284,9 +285,7 @@ class SchedulerService:
         ``log_warn_bytes`` — estimated retained replay-log bytes above
         which the service warns (once) that the unbounded-by-design log
         wants a :meth:`compact_log` cadence. Default 256 MiB."""
-        if solver not in ("jnp", "pallas", "pallas_fused"):
-            raise ValueError(f"unknown solver {solver!r} "
-                             "(want 'jnp'|'pallas'|'pallas_fused')")
+        uses_fused(solver, "proposed")  # raises on an unknown solver
         self.solver = solver
         self.log_requests = log_requests
         self.staging = staging
@@ -318,7 +317,6 @@ class SchedulerService:
                              "reload() it instead of re-registering")
         spec = self.store.add(TenantSpec(name=name, scfg=scfg, ch=ch,
                                          policy=policy, m_avg=m_avg))
-        self._invalidate_step(spec.bucket)
         self._touch(name)
         self.events.emit("admit", tenant=name,
                          bucket=self._bucket_str(spec.bucket))
@@ -331,21 +329,6 @@ class SchedulerService:
         if s is None:
             s = self._bstrs[bkey] = bkey.as_string()
         return s
-
-    def _invalidate_step(self, bkey: BucketKey) -> None:
-        """Drop a bucket's cached step if tenant-set changes can affect
-        it. Only ``solver='pallas'`` bakes the tenant set into the step
-        (its solve_fn is built against the bucket's configuration
-        homogeneity); the jnp/fused steps take every per-tenant quantity
-        as runtime operands, so the SAME jit function serves any tenant
-        count — keeping it preserves the compiled (T, batch)-shape
-        variants across evict/reload churn and across admissions."""
-        if self.solver == "pallas":
-            self._steps.pop(bkey, None)
-            # the new step instance has a fresh jit cache — drop the
-            # host-side mirror too, so re-dispatched shapes count as the
-            # fresh compiles they are
-            self.obs.compiles.forget(bkey)
 
     def raw_structure(self, name: str):
         """An example raw-draw pytree for this tenant (log loading)."""
@@ -530,7 +513,7 @@ class SchedulerService:
         obs = self.obs
         n_warmed = 0
         for bkey, bucket in self.store.buckets().items():
-            step = self._bucket_step(bkey, bucket)
+            step = self._bucket_step(bkey)
             proto = self._proto(bkey.policy)
             bstr = self._bucket_str(bkey)
             b = 1
@@ -558,30 +541,13 @@ class SchedulerService:
         self.events.emit("warmup", shapes_compiled=n_warmed,
                          max_batch=max_batch)
 
-    def _bucket_step(self, bkey: BucketKey, bucket):
+    def _bucket_step(self, bkey: BucketKey):
         if bkey not in self._steps:
-            solve_fn = None
-            if self.solver == "pallas":
-                solve_fn = self._pallas_solve(bkey, bucket)
-            fused = (self.solver == "pallas_fused"
-                     and bkey.policy == "proposed")
             self._steps[bkey] = make_bucket_step(
                 bkey.policy, bkey.n_bucket, bkey.acct_len,
-                bkey.guarantee_one, solve_fn=solve_fn, fused=fused)
+                bkey.guarantee_one,
+                fused=uses_fused(self.solver, bkey.policy))
         return self._steps[bkey]
-
-    def _pallas_solve(self, bkey: BucketKey, bucket):
-        from repro.fl.engine import make_solve_fn
-
-        configs = {(s.scfg, s.ch) for s in bucket.tenants}
-        if len(configs) > 1:
-            raise ValueError(
-                f"solver='pallas' needs bucket {bkey.as_string()!r} to be "
-                "configuration-homogeneous (kernel parameters are "
-                f"compile-time static); it mixes {len(configs)} configs")
-        scfg, ch = next(iter(configs))
-        return make_solve_fn(scfg, ch, "pallas",
-                             block=min(1024, bkey.n_bucket))
 
     def _dispatch_group(self, bkey: BucketKey, reqs: List[_Pending],
                         stage: Optional[_Stage], fl: int):
@@ -591,7 +557,7 @@ class SchedulerService:
         its output to the host, started here)."""
         obs = self.obs
         bucket = self.store.buckets()[bkey]
-        step = self._bucket_step(bkey, bucket)
+        step = self._bucket_step(bkey)
         b_pad = _next_pow2(len(reqs))
         row_ids = [self.store.row(r.tenant) for r in reqs]
         t0 = perf()
@@ -661,7 +627,6 @@ class SchedulerService:
                                  "flush() before evicting")
         spec = self.store.spec(name)
         row = self.store.evict(name)
-        self._invalidate_step(spec.bucket)
         self._last_used.pop(name, None)
         if self.spill_dir is not None:
             fname = re.sub(r"[^\w.-]", "_", name)
@@ -694,7 +659,6 @@ class SchedulerService:
         else:
             row = ref
         out = self.store.readmit(spec, row)
-        self._invalidate_step(spec.bucket)
         self._touch(name)
         self.obs.reloads.inc()
         self.obs.spilled.set(len(self._spilled))

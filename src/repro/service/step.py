@@ -36,12 +36,7 @@ sliced to the tenant's real N — is bitwise-equal to what
 ``run_simulation_scan`` computes for that tenant's configuration on the
 same gains and selection draws, because both sides run the same
 coefficient-operand program (the operand contract,
-``repro/core/scheduler.py``). ``solver="pallas"`` routes the Theorem-2
-solve through the tiled kernel instead (``kernels/scheduler_solve``);
-kernel static parameters must then be shared by the whole bucket, rows
-are mapped sequentially (``lax.map`` — pallas calls don't batch under
-vmap), and the contract is the kernel's usual float32-round-off match,
-not bitwise.
+``repro/core/scheduler.py``).
 
 ``solver="pallas_fused"`` serves ``proposed`` buckets through the fused
 decision megakernel (``kernels/decision_fused.py``): because pallas
@@ -49,8 +44,8 @@ calls don't batch under vmap, the kernel itself is NATIVELY bucket-
 batched — a (B, N/block) grid with one (14,) operand row per bucket
 slot — and only the cheap guarantee/accounting epilogue runs under
 ``jit(vmap)``. Coefficients stay runtime operands, so heterogeneous
-tenants batch in one program (no homogeneity requirement, unlike
-``"pallas"``) and the served rows keep the full BITWISE contract.
+tenants batch in one program and the served rows keep the full BITWISE
+contract.
 """
 
 from __future__ import annotations
@@ -106,11 +101,9 @@ def policy_coeffs(policy: str, scfg: SchedulerConfig, ch: ChannelConfig,
 # (POLICY_DRAWS split), exactly like the client-sharded engine's recipe.
 # --------------------------------------------------------------------------
 
-def _proposed_core(guarantee_one: bool, solve_fn=None):
+def _proposed_core(guarantee_one: bool):
     def core(u, gains, st: PolicyState, c: SolveCoeffs):
-        solve = solve_fn or (
-            lambda g, z: solve_round_coeffs(g, z, c))
-        q, p = solve(gains, st.z)
+        q, p = solve_round_coeffs(gains, st.z, c)
         sel = selection_from_uniform(u, q, guarantee_one)
         z = update_queues_z(st.z, q, p, c)
         return sel, q, p, PolicyState(z, st.aux, st.t + 1)
@@ -118,7 +111,7 @@ def _proposed_core(guarantee_one: bool, solve_fn=None):
     return core
 
 
-def _uniform_core(guarantee_one: bool, solve_fn=None):
+def _uniform_core(guarantee_one: bool):
     # core.scheduler.uniform_decide IS the engine's uniform math — every
     # float op in it is individually correctly-rounded with no contraction
     # pair, so constant-config and operand-config runs agree bit for bit
@@ -129,7 +122,7 @@ def _uniform_core(guarantee_one: bool, solve_fn=None):
     return core
 
 
-def _greedy_core(guarantee_one: bool, solve_fn=None):
+def _greedy_core(guarantee_one: bool):
     def core(raw, gains, st: PolicyState, c: GreedyCoeffs):
         sel, q, p = greedy_decide(gains, c)
         return sel, q, p, PolicyState(st.z, st.aux, st.t + 1)
@@ -214,8 +207,7 @@ def unpack_outputs(packed: np.ndarray, n_bucket: int):
 
 
 def make_bucket_step(policy: str, n_bucket: int, acct_len: int,
-                     guarantee_one: bool, solve_fn=None,
-                     fused: bool = False):
+                     guarantee_one: bool, fused: bool = False):
     """Build the jitted batched serving step for one bucket shape.
 
     Returns ``bucket_step(state, coeffs, acct, n_real, rows, gains, raw)
@@ -246,10 +238,10 @@ def make_bucket_step(policy: str, n_bucket: int, acct_len: int,
     guarantee-one fallback and the blocked accounting folds vmapped over
     rows outside, replaying ``selection_from_uniform``'s and
     ``decision_step``'s exact ops. Bitwise-equal to the default stitched
-    rows (tests/test_decision_fused.py); unlike ``solve_fn`` it needs no
-    bucket homogeneity, since every scalar rides the operand rows.
+    rows (tests/test_decision_fused.py); every scalar rides the operand
+    rows, so heterogeneous tenants share the program.
     """
-    core = _POLICY_CORES[policy](guarantee_one, solve_fn)
+    core = _POLICY_CORES[policy](guarantee_one)
     if fused and policy != "proposed":
         raise ValueError("fused=True needs policy='proposed' (the only "
                          "policy with a fused decision kernel)")
@@ -295,14 +287,9 @@ def make_bucket_step(policy: str, n_bucket: int, acct_len: int,
         if fused:
             sel, q, p, t_comm, power, n_sel, st_new = fused_rows(
                 raw, gains, st_rows, c_rows, a_rows, nr_rows)
-        elif solve_fn is None:
+        else:
             sel, q, p, t_comm, power, n_sel, st_new = jax.vmap(one)(
                 raw, gains, st_rows, c_rows, a_rows, nr_rows)
-        else:
-            # pallas_call does not batch under vmap; map rows sequentially
-            sel, q, p, t_comm, power, n_sel, st_new = jax.lax.map(
-                lambda args: one(*args),
-                (raw, gains, st_rows, c_rows, a_rows, nr_rows))
         new_state = jax.tree.map(
             lambda buf, upd: buf.at[rows].set(upd, mode="drop"),
             state, st_new)

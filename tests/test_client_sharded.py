@@ -39,7 +39,7 @@ import pytest
 from repro.core import ChannelConfig, SchedulerConfig, heterogeneous_sigmas
 from repro.data.synthetic import make_cifar10_like
 from repro.fl.client_shard import make_schedule_runner
-from repro.fl.engine import (SimConfig, make_config_runner, make_solve_fn,
+from repro.fl.engine import (SimConfig, make_config_runner,
                              history_from_trajectory, run_simulation_scan)
 from repro.fl.grid import GridSpec, run_grid
 from repro.fl.simulation import run_simulation
@@ -140,31 +140,26 @@ def test_odd_n_pads_with_dead_lanes(setup):
     assert np.all(shd["n_selected"] <= n)
 
 
-def test_pallas_solver_on_the_sharded_path(setup):
-    """solver="pallas" (interpret off-TPU) rides the per-shard solve: the
-    kernel sees only each shard's client slice, with a shard-friendly
-    block override."""
+def test_fused_decision_on_the_sharded_path(setup):
+    """solver="pallas_fused" (interpret off-TPU) runs the fused kernel on
+    each shard's client slice (``_sharded_proposed_fused``): the whole
+    engine trajectory on the full mesh is bitwise-equal to the stitched
+    per-shard solve on the same mesh."""
     ds, ch, scfg = setup
     sig = heterogeneous_sigmas(N)
     params = make_model("mlp", ds).init_fn(jax.random.PRNGKey(1))
     sim = _sim(rounds=4, policy="proposed",
                client_shards=len(jax.devices()))
-    solve_pal = make_solve_fn(scfg, ch, "pallas", block=128)
-    run_jnp = make_config_runner(ds, sim, scfg, ch, sig)
-    run_pal = make_config_runner(ds, sim, scfg, ch, sig,
-                                 solve_fn=solve_pal)
     key = jax.random.PRNGKey(4)
-    h_jnp = history_from_trajectory(sim.rounds, sim.eval_every, N,
-                                    *run_jnp(params, key))
-    h_pal = history_from_trajectory(sim.rounds, sim.eval_every, N,
-                                    *run_pal(params, key))
-    np.testing.assert_array_equal(h_jnp["n_selected"], h_pal["n_selected"])
-    np.testing.assert_allclose(h_jnp["comm_time"], h_pal["comm_time"],
-                               rtol=1e-4)
-    np.testing.assert_allclose(h_jnp["avg_power"], h_pal["avg_power"],
-                               rtol=1e-4)
-    np.testing.assert_allclose(h_jnp["test_acc"], h_pal["test_acc"],
-                               atol=5e-3)
+    hists = {}
+    for solver in ("jnp", "pallas_fused"):
+        run = make_config_runner(ds, dataclasses.replace(sim, solver=solver),
+                                 scfg, ch, sig)
+        hists[solver] = history_from_trajectory(sim.rounds, sim.eval_every,
+                                                N, *run(params, key))
+    for k in HIST_KEYS:
+        np.testing.assert_array_equal(hists["jnp"][k],
+                                      hists["pallas_fused"][k], err_msg=k)
 
 
 def test_schedule_runner_sequential_vs_sharded_exact(setup):

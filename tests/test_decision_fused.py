@@ -15,16 +15,18 @@ on the nightly kernel-parity leg.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _hyp import given, settings, st
 
 from repro.core import ChannelConfig, SchedulerConfig, make_policy
 from repro.core.policies import POLICIES, init_policy_state
-from repro.fl.decision import (decision_coeffs, decision_step,
-                               make_fused_decision)
+from repro.fl.decision import (SOLVERS, decision_coeffs, decision_step,
+                               make_fused_decision, uses_fused)
 from repro.kernels.decision_fused import (N_DECISION_OPS, decision_fused,
                                           decision_fused_batched,
                                           pack_decision_operands)
@@ -80,6 +82,12 @@ def _stitched(co, key, gains, st, active=None, cfg=CFG):
 def _fused(co, key, gains, st, active=None, cfg=CFG, block=BLOCK):
     fd = make_fused_decision(cfg, co, block=block)
     return fd(None, None, key, gains, st, valid=active)
+
+
+# module-level jits: every test below that varies only the coefficient
+# operands (lambda, V) shares one compiled program per shape
+_STITCHED = jax.jit(_stitched)
+_FUSED = jax.jit(_fused)
 
 
 def _assert_decisions_equal(a, b):
@@ -145,6 +153,74 @@ def test_masked_block_boundary_lanes(n=3 * BLOCK + 17):
     z_exp = np.maximum(np.asarray(z) - np.float32(CH.p_bar),
                        np.float32(0.0))[inactive]
     np.testing.assert_array_equal(np.asarray(b[6].z)[inactive], z_exp)
+
+
+@pytest.mark.parametrize("solver", ["jnp", "pallas_fused"])
+def test_masked_step_inactive_lanes_at_block_boundaries(solver):
+    """Inactive sentinel lanes sitting exactly on kernel block boundaries,
+    with branch-boundary states, are never selected and take q = 0 exactly
+    — on the stitched decision and the fused kernel alike — and no lane
+    (active, inactive, or kernel pad) emits NaN/inf."""
+    n = 3 * BLOCK + 17
+    cfg = SchedulerConfig(n_clients=n, model_bits=32 * 555178.0)
+    decide = _fused if uses_fused(solver, "proposed") else _stitched
+    gains, z = _boundary_states(n)
+    active = _block_boundary_mask(n)
+    st0 = init_policy_state("proposed", n)._replace(z=z)
+    sel, q, p, _, _, _, st1 = decide(decision_coeffs(cfg, CH),
+                                     jax.random.PRNGKey(0), gains, st0,
+                                     active, cfg=cfg)
+    sel, q, p = np.asarray(sel), np.asarray(q), np.asarray(p)
+    inactive = ~np.asarray(active)
+    assert not sel[inactive].any()
+    np.testing.assert_array_equal(q[inactive], 0.0)
+    assert np.isfinite(q).all() and np.isfinite(p).all()
+    assert np.isfinite(np.asarray(st1.z)).all()
+    assert (np.asarray(st1.z) >= 0.0).all()
+
+
+def _assert_fused_matches_stitched(lam, v, n, seed):
+    """Fused == stitched, bitwise on every output, for one (lambda, V) at
+    one size: the coefficients change only runtime operands, so the two
+    programs are the module-level ones."""
+    cfg = SchedulerConfig(n_clients=100, model_bits=32 * 555178.0, lam=lam,
+                          V=v)
+    co = decision_coeffs(cfg, CH)
+    gains, z = _states(jax.random.PRNGKey(seed), n)
+    st0 = init_policy_state("proposed", n)._replace(z=z)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), 2)
+    b = _FUSED(co, key, gains, st0)
+    _assert_decisions_equal(_STITCHED(co, key, gains, st0), b)
+    for x in (b[1], b[2], b[6].z):
+        assert np.isfinite(np.asarray(x)).all()
+
+
+@settings(deadline=None, max_examples=10)
+@given(st.integers(min_value=0, max_value=2 ** 31 - 1),    # PRNG seed
+       st.floats(min_value=0.1, max_value=1e3),            # lambda
+       st.floats(min_value=1.0, max_value=1e5))            # V
+def test_fused_stitched_parity_property(seed, lam, v):
+    """Property form: random (lambda, V) x random states at a
+    block-straddling size keep the fused kernel bitwise on the stitched
+    decision."""
+    _assert_fused_matches_stitched(lam, v, BLOCK + 1, seed)
+
+
+_SWEEP_RNG = np.random.default_rng(7)
+SWEEP_CONFIGS = [(float(10 ** _SWEEP_RNG.uniform(-1, 3)),
+                  float(10 ** _SWEEP_RNG.uniform(0, 5)),
+                  int(_SWEEP_RNG.integers(0, 2 ** 31)))
+                 for _ in range(6)]
+
+
+@pytest.mark.parametrize("lam,v,seed", SWEEP_CONFIGS,
+                         ids=[f"cfg{i}" for i in range(len(SWEEP_CONFIGS))])
+def test_fused_stitched_parity_deterministic_sweep(lam, v, seed):
+    """Fixed-seed fallback for the property above (hypothesis is
+    optional): one (lambda, V) per case, at four sizes around a block
+    boundary."""
+    for i, n in enumerate((1, BLOCK - 1, BLOCK, BLOCK + 1)):
+        _assert_fused_matches_stitched(lam, v, n, seed + i)
 
 
 def test_failed_lanes_stay_charged():
@@ -355,3 +431,104 @@ def test_service_rejects_unknown_solver_and_fused_baseline():
     svc.submit("u", g, key=jax.random.PRNGKey(0))
     out = svc.flush()["u"]
     assert out.sel.shape == (n,)
+
+
+# ---------------------------------------------------------------------------
+# The one decision path: uses_fused picks the kernel, and nothing else does.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("solver,policy,fused", [
+    ("jnp", "proposed", False), ("jnp", "uniform", False),
+    ("pallas_fused", "proposed", True), ("pallas_fused", "uniform", False),
+    ("pallas_fused", "greedy_channel", False)])
+def test_uses_fused_truth_table(solver, policy, fused):
+    assert solver in SOLVERS
+    assert uses_fused(solver, policy) is fused
+
+
+@pytest.mark.parametrize("solver", ["pallas", "cuda", ""])
+def test_uses_fused_rejects_other_names(solver):
+    with pytest.raises(ValueError, match="'jnp', 'pallas_fused'"):
+        uses_fused(solver, "proposed")
+
+
+def _holds_fused_kernel(text):
+    """The traced program holds a ``pallas_call`` of the fused kernel
+    (1D or bucket-batched). On the CPU the kernel runs in interpret mode,
+    whose lowered text no longer names it; the jaxpr still does."""
+    return re.search(r"\bname=decision_fused(_batched)?\b", text) is not None
+
+
+@pytest.fixture(scope="module")
+def tiny_fl():
+    from repro.data.synthetic import make_cifar10_like
+    from repro.models.registry import make_model
+    n = 24
+    ds = make_cifar10_like(jax.random.PRNGKey(0), n_clients=n,
+                           per_client=16, n_test=32, h=8, w=8)
+    params = make_model("mlp", ds).init_fn(jax.random.PRNGKey(1))
+    return (ds, params, dataclasses.replace(CFG, n_clients=n),
+            ChannelConfig(n_clients=n), jnp.ones((n,), jnp.float32))
+
+
+def _engine_text(tiny_fl, solver, population=None):
+    from repro.fl.engine import SimConfig, init_carry, make_chunk_runner
+    ds, params, scfg, ch, sig = tiny_fl
+    sim = SimConfig(rounds=2, eval_every=1, m_cap=4, batch=4, local_steps=1,
+                    eval_size=32, model="mlp", solver=solver,
+                    population=population)
+    run = make_chunk_runner(ds, sim, scfg, ch, sig)
+    carry = init_carry(jax.random.PRNGKey(2), params, scfg, sim, sig, ch)
+    return str(jax.make_jaxpr(lambda c: run(c, 1))(carry))
+
+
+def _schedule_text(tiny_fl, solver):
+    from repro.fl.client_shard import (init_schedule_carry,
+                                       make_schedule_chunk_runner)
+    _, _, scfg, ch, sig = tiny_fl
+    run = make_schedule_chunk_runner(sig, scfg, ch, solver=solver, m_cap=4)
+    carry = init_schedule_carry(jax.random.PRNGKey(3), sig, ch)
+    return str(jax.make_jaxpr(lambda c: run(c, 2))(carry))
+
+
+def _service_text(tiny_fl, solver, monkeypatch):
+    from repro.service import batching
+    _, _, scfg, ch, _ = tiny_fl
+    texts = []
+    real = batching.make_bucket_step
+
+    def make(*a, **k):
+        step = real(*a, **k)
+
+        def traced(*args):
+            texts.append(str(jax.make_jaxpr(step)(*args)))
+            return step(*args)
+        return traced
+    monkeypatch.setattr(batching, "make_bucket_step", make)
+    svc = batching.SchedulerService(solver=solver)
+    svc.add_tenant("t", scfg, ch)
+    svc.submit("t", np.ones(scfg.n_clients, np.float32),
+               key=jax.random.PRNGKey(4))
+    svc.flush()
+    assert len(texts) == 1
+    return texts[0]
+
+
+@pytest.mark.parametrize("solver", ["jnp", "pallas_fused"])
+@pytest.mark.parametrize("path", ["engine", "population", "schedule",
+                                  "service"])
+def test_program_runs_fused_kernel_iff_uses_fused(tiny_fl, monkeypatch,
+                                                  path, solver):
+    """The program each consumer builds for ``proposed`` holds the fused
+    kernel exactly when ``uses_fused`` says so: the engine round, the
+    population round, the scheduling chunk runner and the service's
+    bucket step."""
+    if path == "engine":
+        text = _engine_text(tiny_fl, solver)
+    elif path == "population":
+        text = _engine_text(tiny_fl, solver, population=())
+    elif path == "schedule":
+        text = _schedule_text(tiny_fl, solver)
+    else:
+        text = _service_text(tiny_fl, solver, monkeypatch)
+    assert _holds_fused_kernel(text) is uses_fused(solver, "proposed")

@@ -1,4 +1,4 @@
-"""Scan-compiled engine: parity with the legacy loop, Pallas solve in-round,
+"""Scan-compiled engine: parity with the legacy loop, the solver switch,
 and the policy x seed sweep (repro/fl/engine.py)."""
 
 import dataclasses
@@ -11,7 +11,7 @@ import pytest
 from repro.core import ChannelConfig, SchedulerConfig, heterogeneous_sigmas
 from repro.data.synthetic import make_cifar10_like, make_lm_federated
 from repro.fl.engine import (SimConfig, eval_rounds, history_from_trajectory,
-                             make_solve_fn, run_simulation_scan, run_sweep)
+                             run_simulation_scan, run_sweep)
 from repro.fl.round import pack_participants
 from repro.fl.simulation import run_simulation, run_simulation_loop
 from repro.models.cnn import CNNConfig, init_cnn
@@ -166,44 +166,59 @@ def test_run_simulation_dispatches_on_engine(small_setup):
                        sig)
 
 
-def test_pallas_solver_matches_jnp_inside_round(small_setup):
-    """solver="pallas" (interpret off-TPU) reproduces the jnp closed form
-    through a full simulated trajectory, not just on random inputs."""
-    ds, params, ch, scfg = small_setup
-    sig = heterogeneous_sigmas(N)
-    sim = _sim(rounds=6, eval_every=5, local_steps=2)
-    h_jnp = run_simulation_scan(jax.random.PRNGKey(4), params, ds, sim,
-                                scfg, ch, sig)
-    h_pal = run_simulation_scan(jax.random.PRNGKey(4), params, ds,
-                                dataclasses.replace(sim, solver="pallas"),
-                                scfg, ch, sig)
-    np.testing.assert_array_equal(h_jnp["n_selected"], h_pal["n_selected"])
-    np.testing.assert_allclose(h_jnp["comm_time"], h_pal["comm_time"],
-                               rtol=1e-4)
-    np.testing.assert_allclose(h_jnp["avg_power"], h_pal["avg_power"],
-                               rtol=1e-4)
-    np.testing.assert_allclose(h_jnp["test_acc"], h_pal["test_acc"],
-                               atol=5e-3)
+def _solver_entry_points():
+    """Each public entry point that takes a ``solver``, as ``(name,
+    call(setup, solver))`` — the call builds (and where building is lazy,
+    runs) the entry point at a tiny size."""
+    from repro.fl.client_shard import make_schedule_chunk_runner
+    from repro.fl.engine import init_carry, make_chunk_runner
+    from repro.fl.grid import GridSpec, run_grid
+    from repro.fl.population import make_population_round
+    from repro.service import SchedulerService
+
+    def chunk_runner(setup, solver):
+        ds, params, ch, scfg = setup
+        sig = heterogeneous_sigmas(N)
+        sim = _sim(solver=solver)
+        run = make_chunk_runner(ds, sim, scfg, ch, sig)
+        run(init_carry(jax.random.PRNGKey(0), params, scfg, sim, sig, ch), 1)
+
+    def population_round(setup, solver):
+        ds, _, ch, scfg = setup
+        make_population_round(ds, _sim(solver=solver, population=()), scfg,
+                              ch, heterogeneous_sigmas(N))
+
+    def schedule_chunk_runner(setup, solver):
+        _, _, ch, scfg = setup
+        make_schedule_chunk_runner(heterogeneous_sigmas(N), scfg, ch,
+                                   solver=solver)
+
+    def grid(setup, solver):
+        ds, params, ch, scfg = setup
+        run_grid(jax.random.PRNGKey(0), params, ds, _sim(solver=solver),
+                 scfg, ch, GridSpec(channels=("rayleigh",),
+                                    sigma_dists=("homogeneous",),
+                                    policies=("proposed",), seeds=(0,)))
+
+    def sweep(setup, solver):
+        _, _, ch, scfg = setup
+        run_sweep(jax.random.PRNGKey(0), heterogeneous_sigmas(N), scfg, ch,
+                  rounds=2, policies=("proposed",), solver=solver)
+
+    def service(setup, solver):
+        SchedulerService(solver=solver)
+
+    return [chunk_runner, population_round, schedule_chunk_runner, grid,
+            sweep, service]
 
 
-def test_solve_fn_pallas_matches_jnp_on_queue_states(small_setup):
-    """Direct q/P agreement on gains and queue values the simulation visits."""
-    _, _, ch, scfg = small_setup
-    key = jax.random.PRNGKey(5)
-    gains = jnp.exp(jax.random.normal(key, (N,)))
-    z = jnp.abs(jax.random.normal(jax.random.fold_in(key, 1), (N,))) * 10
-    q_j, p_j = make_solve_fn(scfg, ch, "jnp")(gains, z)
-    q_p, p_p = make_solve_fn(scfg, ch, "pallas")(gains, z)
-    np.testing.assert_allclose(np.asarray(q_j), np.asarray(q_p), rtol=1e-5,
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(p_j), np.asarray(p_p), rtol=1e-5,
-                               atol=1e-3)
-
-
-def test_make_solve_fn_rejects_unknown_solver(small_setup):
-    _, _, ch, scfg = small_setup
-    with pytest.raises(ValueError):
-        make_solve_fn(scfg, ch, "cuda")
+@pytest.mark.parametrize("entry", _solver_entry_points(),
+                         ids=lambda f: f.__name__)
+def test_pallas_solver_rejected_at_every_entry_point(small_setup, entry):
+    """Every entry point refuses a solver outside ``SOLVERS`` — the
+    retired ``"pallas"`` included — and names both valid values."""
+    with pytest.raises(ValueError, match="'jnp', 'pallas_fused'"):
+        entry(small_setup, "pallas")
 
 
 def test_run_sweep_shapes_and_policy_ordering(small_setup):

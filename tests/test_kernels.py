@@ -107,21 +107,3 @@ def test_ssd_decode_step_matches_scan():
     np.testing.assert_allclose(np.asarray(y_d), np.asarray(y_r), atol=1e-5,
                                rtol=1e-4)
 
-
-# ------------------------------------------------------- scheduler solve
-
-@settings(deadline=None, max_examples=10)
-@given(st.integers(3, 700), st.floats(10.0, 1e4), st.floats(0.5, 200.0))
-def test_scheduler_kernel_matches_core(n_clients, v, lam):
-    key = jax.random.PRNGKey(n_clients)
-    gains = jnp.exp(jax.random.normal(key, (n_clients,)))
-    z = jnp.abs(jax.random.normal(jax.random.fold_in(key, 1),
-                                  (n_clients,))) * 20
-    kw = dict(n=n_clients, v=v, lam=lam, ell=32 * 555178.0, bandwidth=22e6,
-              noise=1.0, p_max=100.0, p_bar=1.0)
-    qk, pk = ops.scheduler_solve(gains, z, **kw)
-    qr, pr = ref.scheduler_solve_ref(gains, z, **kw)
-    np.testing.assert_allclose(np.asarray(qk), np.asarray(qr), atol=1e-6,
-                               rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(pk), np.asarray(pr), atol=1e-3,
-                               rtol=1e-5)

@@ -343,7 +343,7 @@ def test_same_tenant_twice_in_one_flush_serves_in_order():
 
 
 # --------------------------------------------------------------------------
-# Validation + the pallas solve switch.
+# Validation and failure atomicity.
 # --------------------------------------------------------------------------
 
 def test_validation_errors():
@@ -384,13 +384,18 @@ def test_failed_flush_logs_nothing():
     replay log (the log must contain exactly the requests whose queue
     updates happened, or replay diverges)."""
     scfg, ch = _configs(n=64)
-    svc = SchedulerService(solver="pallas")
+    svc = SchedulerService()
     svc.add_tenant("x", scfg, ch)
     svc.add_tenant("y", dataclasses.replace(scfg, V=17.0), ch)
     gains = np.ones(64, np.float32)
     svc.submit("x", gains, key=jax.random.PRNGKey(0))
     svc.submit("y", gains, key=jax.random.PRNGKey(1))
-    with pytest.raises(ValueError, match="homogeneous"):
+
+    def boom(*args, **kw):
+        raise RuntimeError("injected first-group failure")
+
+    svc._dispatch_group = boom
+    with pytest.raises(RuntimeError, match="injected"):
         svc.flush()
     assert len(svc.log) == 0 and svc.log.n_requests == 0
 
@@ -691,32 +696,6 @@ def test_warmup_leaves_state_bitwise_untouched():
         np.testing.assert_array_equal(d1[nm].sel, d2[nm].sel, err_msg=nm)
 
 
-def test_pallas_solver_bucket():
-    """solver='pallas' serves a configuration-homogeneous bucket through
-    the tiled kernel (interpret off-TPU) — matching the jnp service to the
-    kernel's float32 round-off — and rejects heterogeneous buckets."""
-    scfg, ch = _configs(n=64)
-    gains = np.abs(np.asarray(
-        jax.random.normal(jax.random.PRNGKey(0), (64,)))) + 0.05
-    key = jax.random.PRNGKey(1)
-
-    svc_j = SchedulerService(solver="jnp")
-    svc_p = SchedulerService(solver="pallas")
-    for svc in (svc_j, svc_p):
-        svc.add_tenant("t", scfg, ch)
-        svc.submit("t", gains, key=key)
-    dj, dp = svc_j.flush()["t"], svc_p.flush()["t"]
-    np.testing.assert_allclose(dp.q, dj.q, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(dp.p, dj.p, rtol=1e-5, atol=1e-3)
-
-    svc_bad = SchedulerService(solver="pallas")
-    svc_bad.add_tenant("x", scfg, ch)
-    svc_bad.add_tenant("y", dataclasses.replace(scfg, V=17.0), ch)
-    svc_bad.submit("x", gains, key=key)
-    with pytest.raises(ValueError, match="homogeneous"):
-        svc_bad.flush()
-
-
 # --------------------------------------------------------------------------
 # One device-to-host transfer a serve group: the packed step output.
 # --------------------------------------------------------------------------
@@ -726,12 +705,15 @@ def _as_six(*outs):
 
 
 @pytest.mark.parametrize("n", [40, 100, 3597], ids=["b64", "b128", "b4096"])
-@pytest.mark.parametrize("solver", ["jnp", "pallas", "pallas_fused"])
+@pytest.mark.parametrize("solver,staging", [("jnp", True), ("jnp", False),
+                                            ("pallas_fused", True)],
+                         ids=["jnp", "jnp-legacy", "pallas_fused"])
 def test_packed_pull_serves_the_steps_outputs_bitwise(monkeypatch, solver,
-                                                      n):
+                                                      staging, n):
     """Each served Decision is, bit for bit and dtype for dtype, the
     step's six outputs as the step computed them before packing, and each
-    serve group costs one counted device-to-host transfer."""
+    serve group costs one counted device-to-host transfer — whichever
+    batch builder (staged arenas or the legacy per-request pad) fed it."""
     from repro.service import batching
     from repro.service import step as step_mod
 
@@ -753,7 +735,7 @@ def test_packed_pull_serves_the_steps_outputs_bitwise(monkeypatch, solver,
     monkeypatch.setattr(batching, "make_bucket_step", make)
 
     scfg, ch = _configs(n=n)
-    svc = SchedulerService(solver=solver, telemetry=True)
+    svc = SchedulerService(solver=solver, staging=staging, telemetry=True)
     for name in ("a", "b"):
         svc.add_tenant(name, scfg, ch)
     rng = np.random.default_rng(n)

@@ -11,8 +11,7 @@ kernel's own name, the name a profiler trace gives its device time:
 * ``decision_fused`` at N = 10^6, with and without the activity/validity
   masks (the engines' fused decision);
 * ``decision_fused_batched`` at one service bucket shape per width
-  32 / 128 / 512 (the ``solver="pallas_fused"`` service);
-* ``scheduler_solve`` at N = 10^6.
+  32 / 128 / 512 (the ``solver="pallas_fused"`` service).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
@@ -28,7 +27,6 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decision_fused import (N_DECISION_OPS, decision_fused,
                                           decision_fused_batched)
-from repro.kernels.scheduler_solve import scheduler_solve
 
 N = 1_000_000
 
@@ -97,12 +95,3 @@ def test_decision_fused_batched_compiles(one_chip, width, batch):
     assert _holds_kernel(_compiled_text(fn, *lanes, ops, valid),
                          "decision_fused_batched")
 
-
-def test_scheduler_solve_compiles(one_chip):
-    def fn(g, z):
-        return scheduler_solve(g, z, n=N, v=1000.0, lam=10.0,
-                               ell=32 * 555178.0, bandwidth=22e6, noise=1.0,
-                               p_max=100.0, p_bar=1.0, interpret=False)
-
-    text = _compiled_text(fn, _shape(one_chip, (N,)), _shape(one_chip, (N,)))
-    assert _holds_kernel(text, "scheduler_solve")
